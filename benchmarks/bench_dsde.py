@@ -1,7 +1,7 @@
 """Paper Fig. 7b: dynamic sparse data exchange — accumulate protocol vs
 alltoall / reduce-scatter baselines, k=6 random neighbors per process."""
 import jax
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import emit, time_fn

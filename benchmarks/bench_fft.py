@@ -10,7 +10,7 @@ data of a plane as soon as it is available").
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import emit, time_fn

@@ -3,7 +3,7 @@ in the paper; scaled-down batch here, same protocol)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import emit, time_fn

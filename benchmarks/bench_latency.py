@@ -9,7 +9,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import emit, time_fn

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import emit, time_fn
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import plan as plan_mod, rma
 from repro.core.perfmodel import DEFAULT_MODEL
 from repro.core.rma import OpCounter

@@ -2,7 +2,7 @@
 overlapped compute vs bulk-synchronous message-passing formulation."""
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import emit, time_fn

@@ -14,7 +14,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import emit, time_fn
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import dsde
 from repro.core.perfmodel import DEFAULT_MODEL
 from repro.rmaq import channel as rch, flow, notify, queue as rq

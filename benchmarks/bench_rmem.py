@@ -23,7 +23,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import emit, time_fn
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.perfmodel import DEFAULT_MODEL
 from repro.rmem import heap
 from repro.serve.disagg import DisaggConfig, DisaggEngine

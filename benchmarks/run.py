@@ -58,7 +58,7 @@ def emit_rma_plan_json(path: str = "BENCH_rma_plan.json", k: int = 32,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core import plan as plan_mod, rma
     from repro.core.perfmodel import DEFAULT_MODEL
     from repro.core.rma import OpCounter

@@ -9,7 +9,7 @@ Validates the pencil-decomposed FFT against a single-device jnp.fft.fftn.
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import collectives

@@ -10,7 +10,7 @@ of sync mode (k=2 => PSCW), and agreement with a single-device stencil.
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import collectives

@@ -11,7 +11,7 @@ to the same exchange; and that token->expert assignment is conserved.
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import dsde

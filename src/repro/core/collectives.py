@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 
 from . import plan as plan_mod, rma
 
@@ -39,7 +38,7 @@ def ring_all_gather(x: Array, axis: str, bidirectional: bool = True) -> Array:
     put can overlap with the consumer's compute on already-arrived shards
     (the fused version lives in `kernels/ring_matmul`).
     """
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     me = lax.axis_index(axis)
     if p == 1:
         return x[None]
@@ -101,7 +100,7 @@ def ring_reduce_scatter(
     accumulates it into its running slot — the slotted MPI_Accumulate
     pattern (§2.4) in ring order.
     """
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     me = lax.axis_index(axis)
     if p == 1:
         return x[0]
@@ -123,7 +122,7 @@ def ring_reduce_scatter(
 
 def all_reduce(x: Array, axis: str, op: Callable = jnp.add) -> Array:
     """RS + AG ring all-reduce over one axis, built purely on RMA puts."""
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     if p == 1:
         return x
     flat = x.reshape(-1)
@@ -142,7 +141,7 @@ def hierarchical_all_reduce(x: Array, inner_axis: str, outer_axis: str) -> Array
     (data, pod) hierarchy: the expensive outer (DCN) axis only ever carries
     1/inner_size of the payload.
     """
-    p = compat.axis_size(inner_axis)
+    p = jax.lax.axis_size(inner_axis)
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % p
     flat = jnp.pad(flat, (0, pad))

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 
 from . import collectives, plan as plan_mod, rma  # noqa: F401  (rma: API re-export site)
 
@@ -62,7 +61,7 @@ def exchange_accumulate(
     2's payload movement is a single all-to-all of the slot buffers — i.e.
     p one-sided puts issued in one epoch.
     """
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     n = data.shape[0]
 
     # one epoch-scoped plan (DESIGN.md §8): the counter accumulate and the
@@ -121,7 +120,7 @@ def exchange_alltoall_baseline(
     baseline required by the paper's Fig. 7b.
     """
     # identical packing, but counts move in their own full round first
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     res = exchange_accumulate(data, targets, axis, capacity_per_pair)
     # model the extra dense count round (payload identical under SPMD)
     _ = collectives.all_to_all(jnp.zeros((p,), jnp.int32), axis)
@@ -132,7 +131,7 @@ def exchange_reduce_scatter_baseline(
     data: Array, targets: Array, axis: str, capacity_per_pair: int
 ) -> DSDEResult:
     """Baseline 2: reduce_scatter for counts, then personalized sends."""
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     onehot = jax.nn.one_hot(targets, p, dtype=jnp.int32)
     counts = lax.psum_scatter(onehot.sum(0), axis, tiled=True)  # my recv total
     res = exchange_accumulate(data, targets, axis, capacity_per_pair)
@@ -155,7 +154,7 @@ def exchange_queue(
     """
     from repro.rmaq import queue as rq
 
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     n, d = data.shape
     cap = max(2, p * capacity_per_pair)
     cap = 1 << (cap - 1).bit_length()                 # next power of two
@@ -196,7 +195,7 @@ def moe_dispatch(
     Experts are sharded over `axis` (EP); each rank owns n_experts/p of them.
     Returns per-local-expert batches plus combine metadata for `moe_combine`.
     """
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     me = lax.axis_index(axis)
     n_tok, d = tokens.shape
     top_k = expert_idx.shape[1]
@@ -271,7 +270,7 @@ def moe_combine(
     The return trip is the same one-sided exchange reversed, followed by a
     gate-weighted scatter-add into the token buffer (slotted accumulate).
     """
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     local_e, slots, d = expert_outputs.shape
     cap = slots // p
 

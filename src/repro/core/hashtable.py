@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 
 from . import dsde, plan as plan_mod
 
@@ -135,7 +134,7 @@ def insert_epoch(
 
     Returns (updated volume, number of items this rank dropped to capacity).
     """
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     owners = hash_owner(keys, p)
     items = jnp.stack([keys, vals], axis=1)  # [n, 2] payload
     res = dsde.exchange_accumulate(items, owners, axis, capacity_per_pair)
@@ -152,7 +151,7 @@ def lookup_epoch(vol: LocalVolume, keys: Array, axis: str, capacity_per_pair: in
     answers back (two one-sided epochs — the MPI-3 get-based formulation).
     Returns (values, found) aligned with `keys`.
     """
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     n = keys.shape[0]
     owners = hash_owner(keys, p)
     qid = jnp.arange(n, dtype=jnp.int64)

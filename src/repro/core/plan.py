@@ -46,7 +46,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import snapshot_delta
 
@@ -223,7 +222,7 @@ def _issue_ppermute(x: Array, axis: str, perm: tuple, shift: Optional[int],
     if backend in ("pallas", "interpret") and shift is not None:
         from repro.kernels.rma import kernel as rma_kernel  # lazy: pallas import
 
-        n = compat.axis_size(axis)
+        n = jax.lax.axis_size(axis)
         return rma_kernel.put_shift_pallas(
             x, shift, axis, n, interpret=(backend == "interpret")
         )
@@ -275,7 +274,7 @@ class RmaPlan:
         return h
 
     def _shift_perm(self, shift: int) -> tuple:
-        n = compat.axis_size(self.axis)
+        n = jax.lax.axis_size(self.axis)
         return tuple((i, (i + shift) % n) for i in range(n))
 
     def put_shift(self, x: Array, shift: int, kind: str = "puts",
@@ -369,7 +368,7 @@ class RmaPlan:
             moved = lax.all_gather(packed, axis)  # [p, W]
 
         off = 0
-        p = compat.axis_size(axis)
+        p = jax.lax.axis_size(axis)
         for op, w in zip(ops, widths):
             if sig[0] == "ppermute":
                 seg = lax.slice_in_dim(moved, off, off + w, axis=0)

@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import snapshot_delta
 
@@ -48,7 +47,7 @@ Array = jax.Array
 
 
 def _axis_size(axis: str) -> int:
-    return compat.axis_size(axis)
+    return jax.lax.axis_size(axis)
 
 
 def _plan(axis: str):

@@ -6,8 +6,6 @@ from __future__ import annotations
 import jax
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 _INTERPRET: bool | None = None
 
 
@@ -28,23 +26,19 @@ def interpret_mode(override: bool | None = None) -> bool:
     return _INTERPRET
 
 
-def neighbor_barrier(axis: str, n: int, interpret: bool = False) -> None:
+def neighbor_barrier(axis: str, n: int) -> None:
     """Barrier with both ring neighbors (paper: post/start matching).
 
     Prevents a device from racing ahead and tearing down buffers while a
     neighbor's DMA is inflight — the same reason FOMPI's start blocks on
-    matching posts.  Skipped under old-JAX interpret mode, where remote
-    semaphore signals are unimplemented and discharged DMAs are synchronous
-    collectives (nothing to race).
+    matching posts.
     """
-    if interpret and not compat.INTERPRET_REMOTE_SIGNAL:
-        return
     me = jax.lax.axis_index(axis)
     left = jax.lax.rem(me - 1 + n, n)
     right = jax.lax.rem(me + 1, n)
     sem = pltpu.get_barrier_semaphore()
-    pltpu.semaphore_signal(sem, device_id=compat.remote_device_id(left),
+    pltpu.semaphore_signal(sem, device_id=(left,),
                            device_id_type=pltpu.DeviceIdType.MESH)
-    pltpu.semaphore_signal(sem, device_id=compat.remote_device_id(right),
+    pltpu.semaphore_signal(sem, device_id=(right,),
                            device_id_type=pltpu.DeviceIdType.MESH)
     pltpu.semaphore_wait(sem, 2)

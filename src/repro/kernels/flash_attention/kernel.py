@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NEG_INF = -1e30
 
@@ -79,7 +78,7 @@ def flash_attention_pallas(
     causal: bool = True,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: bool = True,
+    *, interpret: bool,
 ) -> jax.Array:
     B, Hq, Sq, hd = q.shape
     _, Hkv, Sk, _ = k.shape
@@ -113,9 +112,9 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=compat.pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
-        interpret=compat.pallas_interpret_params() if interpret else False,
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(qp, kp, vp)
     return out[:, :, :Sq]

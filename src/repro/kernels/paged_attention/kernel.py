@@ -43,7 +43,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.kernels.common import neighbor_barrier as _neighbor_barrier
 
 NEG_INF = -1e30
@@ -99,7 +98,7 @@ def _paged_attention_kernel(causal: bool, pt: int, Sq: int, Sk: int,
 def paged_attention_pallas(q: jax.Array, kv_pages: jax.Array,
                            ids: jax.Array, scale: float | None = None,
                            causal: bool = False,
-                           interpret: bool = True) -> jax.Array:
+                           *, interpret: bool) -> jax.Array:
     """q [m, Sq, hd], kv_pages [n_pages, pt, 2, hd], ids [m, k] int32
     → [m, Sq, hd].  Grid (m, k) with pages innermost/arbitrary; the ids
     table is scalar-prefetched so page ids[i, j]'s DMA is issued straight
@@ -133,16 +132,16 @@ def paged_attention_pallas(q: jax.Array, kv_pages: jax.Array,
         functools.partial(_paged_attention_kernel, causal, pt, Sq, k * pt),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, Sq, hd), q.dtype),
-        compiler_params=compat.pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-        interpret=compat.pallas_interpret_params() if interpret else False,
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(ids, qs, kv_pages)
 
 
 # ----------------------------------------------------------------- cross-rank
 def _paged_attention_shift_kernel(axis, n, shift, n_pages, pt, Sq, causal,
-                                  scale, interpret,
+                                  scale,
                                   kv_ref, ids_ref, q_ref, o_ref,
                                   req_ids, send0, send1, stage0, stage1,
                                   m_ref, l_ref, acc_ref,
@@ -154,14 +153,14 @@ def _paged_attention_shift_kernel(axis, n, shift, n_pages, pt, Sq, causal,
     k = ids_ref.shape[0]
     Sk = k * pt
 
-    _neighbor_barrier(axis, n, interpret)
+    _neighbor_barrier(axis, n)
 
     # ---- 1. request: id lists swap places around the ring (one DMA); my
     # scratch ends up holding `back`'s wanted page ids
     req = pltpu.make_async_remote_copy(
         src_ref=ids_ref, dst_ref=req_ids,
         send_sem=isend, recv_sem=irecv,
-        device_id=compat.remote_device_id(dst),
+        device_id=(dst,),
         device_id_type=pltpu.DeviceIdType.MESH,
     )
     req.start()
@@ -188,7 +187,7 @@ def _paged_attention_shift_kernel(axis, n, shift, n_pages, pt, Sq, causal,
         rep = pltpu.make_async_remote_copy(
             src_ref=sends[slot], dst_ref=stages[slot],
             send_sem=sems[slot][0], recv_sem=sems[slot][1],
-            device_id=compat.remote_device_id(back),
+            device_id=(back,),
             device_id_type=pltpu.DeviceIdType.MESH,
         )
         rep.start()
@@ -208,12 +207,11 @@ def _paged_attention_shift_kernel(axis, n, shift, n_pages, pt, Sq, causal,
     o_ref[...] = (acc_ref[...]
                   / jnp.maximum(l_ref[...], 1e-30)[:, None]).astype(o_ref.dtype)
 
-    if not (interpret and not compat.INTERPRET_REMOTE_SIGNAL):
-        pltpu.semaphore_signal(notify_sem, inc=1,
-                               device_id=compat.remote_device_id(back),
-                               device_id_type=pltpu.DeviceIdType.MESH)
-        pltpu.semaphore_wait(notify_sem, 1)
-    _neighbor_barrier(axis, n, interpret)       # epoch close
+    pltpu.semaphore_signal(notify_sem, inc=1,
+                           device_id=(back,),
+                           device_id_type=pltpu.DeviceIdType.MESH)
+    pltpu.semaphore_wait(notify_sem, 1)
+    _neighbor_barrier(axis, n)       # epoch close
 
 
 def paged_attention_shift_pallas(q: jax.Array, kv_pages: jax.Array,
@@ -221,7 +219,7 @@ def paged_attention_shift_pallas(q: jax.Array, kv_pages: jax.Array,
                                  axis: str, n: int,
                                  scale: float | None = None,
                                  causal: bool = False,
-                                 interpret: bool = True,
+                                 *, interpret: bool,
                                  collective_id: int = 7) -> jax.Array:
     """q [Sq, hd], kv_pages [n_pages, pt, 2, hd], ids [k] int32 →
     [Sq, hd]: attend over pages `ids` of rank (me+shift)'s pool, streamed
@@ -234,7 +232,7 @@ def paged_attention_shift_pallas(q: jax.Array, kv_pages: jax.Array,
     page_stage = pltpu.VMEM((1, pt, 2, hd), kv_pages.dtype)
     return pl.pallas_call(
         functools.partial(_paged_attention_shift_kernel, axis, n, shift,
-                          n_pages, pt, Sq, causal, scale, interpret),
+                          n_pages, pt, Sq, causal, scale),
         out_shape=jax.ShapeDtypeStruct((Sq, hd), q.dtype),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
                   pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -252,7 +250,7 @@ def paged_attention_shift_pallas(q: jax.Array, kv_pages: jax.Array,
             pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.REGULAR,
         ],
-        compiler_params=compat.pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             collective_id=collective_id),
-        interpret=compat.pallas_interpret_params() if interpret else False,
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(kv_pages, ids, q)

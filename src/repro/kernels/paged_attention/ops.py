@@ -6,8 +6,8 @@ import functools
 
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.kernels.common import interpret_mode
 
 from . import kernel

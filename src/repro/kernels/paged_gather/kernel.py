@@ -31,11 +31,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.kernels.common import neighbor_barrier as _neighbor_barrier
 
 
-def _paged_gather_kernel(axis, n, shift, n_pages, interpret,
+def _paged_gather_kernel(axis, n, shift, n_pages,
                          pages_ref, ids_ref, o_ref,
                          req_ids, pack,
                          isend, irecv, psend, precv, notify_sem):
@@ -44,14 +43,14 @@ def _paged_gather_kernel(axis, n, shift, n_pages, interpret,
     back = jax.lax.rem(me - shift + n, n)      # who reads MY pool
     k = ids_ref.shape[0]
 
-    _neighbor_barrier(axis, n, interpret)
+    _neighbor_barrier(axis, n)
 
     # ---- 1. request: my page ids fly to my target's scratch; symmetric
     # issue means my own scratch receives `back`'s ids (the lookup get)
     req = pltpu.make_async_remote_copy(
         src_ref=ids_ref, dst_ref=req_ids,
         send_sem=isend, recv_sem=irecv,
-        device_id=compat.remote_device_id(dst),
+        device_id=(dst,),
         device_id_type=pltpu.DeviceIdType.MESH,
     )
     req.start()
@@ -69,30 +68,28 @@ def _paged_gather_kernel(axis, n, shift, n_pages, interpret,
     rep = pltpu.make_async_remote_copy(
         src_ref=pack, dst_ref=o_ref,
         send_sem=psend, recv_sem=precv,
-        device_id=compat.remote_device_id(back),
+        device_id=(back,),
         device_id_type=pltpu.DeviceIdType.MESH,
     )
     rep.start()
     rep.wait()                                  # my o_ref holds MY pages
 
-    if not (interpret and not compat.INTERPRET_REMOTE_SIGNAL):
-        pltpu.semaphore_signal(notify_sem, inc=1,
-                               device_id=compat.remote_device_id(back),
-                               device_id_type=pltpu.DeviceIdType.MESH)
-        pltpu.semaphore_wait(notify_sem, 1)
-    _neighbor_barrier(axis, n, interpret)       # epoch close
+    pltpu.semaphore_signal(notify_sem, inc=1,
+                           device_id=(back,),
+                           device_id_type=pltpu.DeviceIdType.MESH)
+    pltpu.semaphore_wait(notify_sem, 1)
+    _neighbor_barrier(axis, n)       # epoch close
 
 
 def paged_gather_pallas(pages: jax.Array, ids: jax.Array, shift: int,
-                        axis: str, n: int, interpret: bool = True,
+                        axis: str, n: int, *, interpret: bool,
                         collective_id: int = 6) -> jax.Array:
     """pages [n_pages, w], ids [k] int32 → [k, w]: rows `ids` of rank
     (me+shift)'s pool, gathered contiguously in one fused reply transfer."""
     n_pages, w = pages.shape
     k = ids.shape[0]
     return pl.pallas_call(
-        functools.partial(_paged_gather_kernel, axis, n, shift, n_pages,
-                          interpret),
+        functools.partial(_paged_gather_kernel, axis, n, shift, n_pages),
         out_shape=jax.ShapeDtypeStruct((k, w), pages.dtype),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
@@ -104,6 +101,6 @@ def paged_gather_pallas(pages: jax.Array, ids: jax.Array, shift: int,
             pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.REGULAR,
         ],
-        compiler_params=compat.pallas_compiler_params(collective_id=collective_id),
-        interpret=compat.pallas_interpret_params() if interpret else False,
+        compiler_params=pltpu.CompilerParams(collective_id=collective_id),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(pages, ids)

@@ -24,29 +24,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
-
 from repro.kernels.common import neighbor_barrier as _neighbor_barrier
 
 
-def _ring_mm_kernel(axis: str, n: int, interpret: bool, x_ref, w_ref, o_ref, buf, send_sem, recv_sem):
+def _ring_mm_kernel(axis: str, n: int, x_ref, w_ref, o_ref, buf, send_sem, recv_sem):
     me = jax.lax.axis_index(axis)
     right = jax.lax.rem(me + 1, n)
     ks = w_ref.shape[0]                       # K/n rows per shard
 
-    _neighbor_barrier(axis, n, interpret)
+    _neighbor_barrier(axis, n)
     buf[0] = w_ref[...]
     o_ref[...] = jnp.zeros_like(o_ref)
 
     def step(i, _):
-        _neighbor_barrier(axis, n, interpret)  # slot-reuse handshake
+        _neighbor_barrier(axis, n)  # slot-reuse handshake
         slot = jax.lax.rem(i, 2)
         nxt = jax.lax.rem(i + 1, 2)
         rdma = pltpu.make_async_remote_copy(
             src_ref=buf.at[slot], dst_ref=buf.at[nxt],
             send_sem=send_sem, recv_sem=recv_sem,
-            device_id=compat.remote_device_id(right), device_id_type=pltpu.DeviceIdType.MESH,
+            device_id=(right,), device_id_type=pltpu.DeviceIdType.MESH,
         )
 
         @pl.when(i < n - 1)
@@ -76,7 +73,7 @@ def ring_matmul_pallas(
     w: jax.Array,        # [K/n, N] local W shard
     axis: str,
     n: int,
-    interpret: bool = True,
+    *, interpret: bool,
     collective_id: int = 2,
 ) -> jax.Array:
     """Returns Y^T? No — returns Y [m, N] = x^T... see dims: out[m, N]."""
@@ -84,7 +81,7 @@ def ring_matmul_pallas(
     ks, N = w.shape
     assert ks * n == K, (K, ks, n)
     return pl.pallas_call(
-        functools.partial(_ring_mm_kernel, axis, n, interpret),
+        functools.partial(_ring_mm_kernel, axis, n),
         out_shape=jax.ShapeDtypeStruct((m, N), jnp.float32),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
@@ -93,6 +90,6 @@ def ring_matmul_pallas(
             pltpu.VMEM((2,) + w.shape, w.dtype),
             pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=compat.pallas_compiler_params(collective_id=collective_id),
-        interpret=compat.pallas_interpret_params() if interpret else False,
+        compiler_params=pltpu.CompilerParams(collective_id=collective_id),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(x_t, w)

@@ -5,7 +5,7 @@ from __future__ import annotations
 import functools
 
 import jax
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.common import interpret_mode

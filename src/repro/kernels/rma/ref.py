@@ -5,11 +5,10 @@ from __future__ import annotations
 import jax
 from jax import lax
 
-from repro import compat
 
 
 def put_shift_ref(x: jax.Array, shift: int, axis: str) -> jax.Array:
-    n = compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     return lax.ppermute(x, axis, [(i, (i + shift) % n) for i in range(n)])
 
 

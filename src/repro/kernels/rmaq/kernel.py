@@ -13,14 +13,10 @@ primitives, mirroring `repro.rmaq.notify`'s XLA path:
     notification, receiver-side tail publish.  The MPSC queue's data plane
     with literal one-sided ops.
 
-Notification semantics per path:
-  * compiled TPU: a remote ``semaphore_signal`` on a REGULAR semaphore is
-    the doorbell; the receiver's ``semaphore_wait`` is the notification
-    (bufferless — no counter window at all).
-  * interpret mode (CPU validation): old-JAX interpret discharge does not
-    implement remote signals, so the count-word DMA carries the
-    notification and the discharged DMAs' synchronous semantics stand in
-    for the wait (see `repro.compat.INTERPRET_REMOTE_SIGNAL`).
+Notification: a remote ``semaphore_signal`` on a REGULAR semaphore is the
+doorbell; the receiver's ``semaphore_wait`` is the notification.  The
+count-word DMA rides the same epoch, so the receiver also holds the count.
+The interpreter (``pltpu.InterpretParams``) runs the same signals.
 
 Interpret-mode discharge also requires a *static* collective schedule (a
 DMA under a rank-divergent conditional would desynchronize the lowered
@@ -38,100 +34,94 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
-
 from repro.kernels.common import neighbor_barrier as _neighbor_barrier
 
 
-def _doorbell(axis: str, n: int, dst, notify_sem, interpret: bool):
+def _doorbell(dst, notify_sem):
     """Remote doorbell: signal the target's notification semaphore, wait for
-    our own — the literal write-with-notification handshake (compiled path;
-    interpret mode relies on the count-word DMA instead)."""
-    if interpret and not compat.INTERPRET_REMOTE_SIGNAL:
-        return
+    our own — the literal write-with-notification handshake."""
     pltpu.semaphore_signal(notify_sem, inc=1,
-                           device_id=compat.remote_device_id(dst),
+                           device_id=(dst,),
                            device_id_type=pltpu.DeviceIdType.MESH)
     pltpu.semaphore_wait(notify_sem, 1)
 
 
 # ----------------------------------------------------------- notified put
-def _notified_put_kernel(axis, n, shift, interpret,
+def _notified_put_kernel(axis, n, shift,
                          x_ref, cnt_ref, o_ref, ocnt_ref,
                          send_sem, recv_sem, csend, crecv, notify_sem):
     me = jax.lax.axis_index(axis)
     dst = jax.lax.rem(me + shift + n, n)
-    _neighbor_barrier(axis, n, interpret)
+    _neighbor_barrier(axis, n)
     payload = pltpu.make_async_remote_copy(
         src_ref=x_ref, dst_ref=o_ref,
         send_sem=send_sem, recv_sem=recv_sem,
-        device_id=compat.remote_device_id(dst),
+        device_id=(dst,),
         device_id_type=pltpu.DeviceIdType.MESH,
     )
     note = pltpu.make_async_remote_copy(
         src_ref=cnt_ref, dst_ref=ocnt_ref,
         send_sem=csend, recv_sem=crecv,
-        device_id=compat.remote_device_id(dst),
+        device_id=(dst,),
         device_id_type=pltpu.DeviceIdType.MESH,
     )
     payload.start()          # MPI_Put (nonblocking)
     note.start()             # counter accumulate riding the same epoch
     payload.wait()
     note.wait()              # MPI_Win_flush: payload + count visible
-    _doorbell(axis, n, dst, notify_sem, interpret)
+    _doorbell(dst, notify_sem)
 
 
 def notified_put_pallas(x: jax.Array, cnt: jax.Array, shift: int, axis: str,
-                        n: int, interpret: bool = True,
+                        n: int, *, interpret: bool,
                         collective_id: int = 3) -> tuple[jax.Array, jax.Array]:
     """Returns (payload delivered into us, notification count delivered)."""
     return pl.pallas_call(
-        functools.partial(_notified_put_kernel, axis, n, shift, interpret),
+        functools.partial(_notified_put_kernel, axis, n, shift),
         out_shape=(jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct(cnt.shape, cnt.dtype)),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)),
         scratch_shapes=[
             pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.REGULAR,
         ],
-        compiler_params=compat.pallas_compiler_params(collective_id=collective_id),
-        interpret=compat.pallas_interpret_params() if interpret else False,
+        compiler_params=pltpu.CompilerParams(collective_id=collective_id),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(x, cnt)
 
 
 # ------------------------------------------------------ notify accumulate
-def _notify_accum_kernel(axis, n, shift, interpret,
+def _notify_accum_kernel(axis, n, shift,
                          cnt_ref, local_ref, o_ref,
                          csend, crecv, incoming, notify_sem):
     """Counter-only notification: accumulate my count into the target's
     notification counter (o = local + what arrived)."""
     me = jax.lax.axis_index(axis)
     dst = jax.lax.rem(me + shift + n, n)
-    _neighbor_barrier(axis, n, interpret)
+    _neighbor_barrier(axis, n)
     note = pltpu.make_async_remote_copy(
         src_ref=cnt_ref, dst_ref=incoming,
         send_sem=csend, recv_sem=crecv,
-        device_id=compat.remote_device_id(dst),
+        device_id=(dst,),
         device_id_type=pltpu.DeviceIdType.MESH,
     )
     note.start()
     note.wait()
-    _doorbell(axis, n, dst, notify_sem, interpret)
+    _doorbell(dst, notify_sem)
     o_ref[...] = local_ref[...] + incoming[...]   # owner-side reduce (§2.4)
 
 
 def notify_accumulate_pallas(cnt: jax.Array, local: jax.Array, shift: int,
-                             axis: str, n: int, interpret: bool = True,
+                             axis: str, n: int, *, interpret: bool,
                              collective_id: int = 4) -> jax.Array:
     return pl.pallas_call(
-        functools.partial(_notify_accum_kernel, axis, n, shift, interpret),
+        functools.partial(_notify_accum_kernel, axis, n, shift),
         out_shape=jax.ShapeDtypeStruct(local.shape, local.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=[
@@ -139,13 +129,13 @@ def notify_accumulate_pallas(cnt: jax.Array, local: jax.Array, shift: int,
             pltpu.VMEM(cnt.shape, cnt.dtype),
             pltpu.SemaphoreType.REGULAR,
         ],
-        compiler_params=compat.pallas_compiler_params(collective_id=collective_id),
-        interpret=compat.pallas_interpret_params() if interpret else False,
+        compiler_params=pltpu.CompilerParams(collective_id=collective_id),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(cnt, local)
 
 
 # ------------------------------------------------------------- queue push
-def _queue_push_kernel(axis, n, shift, capacity, interpret,
+def _queue_push_kernel(axis, n, shift, capacity,
                        buf_ref, ctr_ref, msgs_ref,
                        o_buf, o_ctr, o_sent, o_notif,
                        tctr, my_cnt, in_cnt,
@@ -164,14 +154,14 @@ def _queue_push_kernel(axis, n, shift, capacity, interpret,
     # everyone stages its ring + counters into the output refs first
     o_buf[: capacity] = buf_ref[...]
     o_ctr[...] = ctr_ref[...]
-    _neighbor_barrier(axis, n, interpret)
+    _neighbor_barrier(axis, n)
 
     # ---- fetch the target's (head, tail): send mine to `back`, so my
     # scratch receives my *target's* counters (symmetric SPMD get)
     get_ctr = pltpu.make_async_remote_copy(
         src_ref=ctr_ref, dst_ref=tctr,
         send_sem=gsend, recv_sem=grecv,
-        device_id=compat.remote_device_id(back),
+        device_id=(back,),
         device_id_type=pltpu.DeviceIdType.MESH,
     )
     get_ctr.start()
@@ -190,7 +180,7 @@ def _queue_push_kernel(axis, n, shift, capacity, interpret,
             src_ref=msgs_ref.at[pl.ds(j, 1)],
             dst_ref=o_buf.at[pl.ds(slot, 1)],
             send_sem=dsend, recv_sem=drecv,
-            device_id=compat.remote_device_id(dst),
+            device_id=(dst,),
             device_id_type=pltpu.DeviceIdType.MESH,
         )
         row.start()
@@ -205,13 +195,13 @@ def _queue_push_kernel(axis, n, shift, capacity, interpret,
     note = pltpu.make_async_remote_copy(
         src_ref=my_cnt, dst_ref=in_cnt,
         send_sem=csend, recv_sem=crecv,
-        device_id=compat.remote_device_id(dst),
+        device_id=(dst,),
         device_id_type=pltpu.DeviceIdType.MESH,
     )
     note.start()
     note.wait()
-    _doorbell(axis, n, dst, notify_sem, interpret)
-    _neighbor_barrier(axis, n, interpret)      # epoch close: all puts landed
+    _doorbell(dst, notify_sem)
+    _neighbor_barrier(axis, n)      # epoch close: all puts landed
 
     o_ctr[1] = ctr_ref[1] + in_cnt[0]          # publish tail (owner-side)
     o_sent[0] = accept
@@ -220,7 +210,7 @@ def _queue_push_kernel(axis, n, shift, capacity, interpret,
 
 def queue_push_pallas(buf: jax.Array, ctr: jax.Array, msgs: jax.Array,
                       shift: int, axis: str, n: int, capacity: int,
-                      interpret: bool = True, collective_id: int = 5):
+                      *, interpret: bool, collective_id: int = 5):
     """buf [capacity, w], ctr [2] int32 (head, tail), msgs [k, w].
 
     Returns (buf' [capacity+1, w], ctr', n_sent [1], n_notif [1]); callers
@@ -228,7 +218,7 @@ def queue_push_pallas(buf: jax.Array, ctr: jax.Array, msgs: jax.Array,
     """
     w = buf.shape[1]
     return pl.pallas_call(
-        functools.partial(_queue_push_kernel, axis, n, shift, capacity, interpret),
+        functools.partial(_queue_push_kernel, axis, n, shift, capacity),
         out_shape=(
             jax.ShapeDtypeStruct((capacity + 1, w), buf.dtype),
             jax.ShapeDtypeStruct(ctr.shape, ctr.dtype),
@@ -251,6 +241,6 @@ def queue_push_pallas(buf: jax.Array, ctr: jax.Array, msgs: jax.Array,
             pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.REGULAR,
         ],
-        compiler_params=compat.pallas_compiler_params(collective_id=collective_id),
-        interpret=compat.pallas_interpret_params() if interpret else False,
+        compiler_params=pltpu.CompilerParams(collective_id=collective_id),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(buf, ctr, msgs)

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 
 def _ssm_kernel(block_t: int, decay_ref, drive_ref, c_ref, y_ref, h_ref):
@@ -49,7 +48,7 @@ def ssm_scan_pallas(
     c: jax.Array,        # [B, S, N]
     block_d: int = 256,
     block_t: int = 128,
-    interpret: bool = True,
+    *, interpret: bool,
 ) -> jax.Array:
     """Returns y [B, S, d] = sum_N C_t * h_t."""
     B, S, d, N = decay.shape
@@ -69,8 +68,8 @@ def ssm_scan_pallas(
         out_specs=pl.BlockSpec((1, block_t, block_d), lambda b, id_, it: (b, it, id_)),
         out_shape=jax.ShapeDtypeStruct((B, S, d), decay.dtype),
         scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
-        compiler_params=compat.pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=compat.pallas_interpret_params() if interpret else False,
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(decay, drive, c)

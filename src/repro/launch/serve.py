@@ -12,6 +12,7 @@ import time
 import jax
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve.engine import Request, ServeEngine
 
@@ -25,6 +26,7 @@ def main() -> None:
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=128)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.family in ("audio",):

@@ -18,6 +18,7 @@ from repro.configs import ARCH_IDS, get_config
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.data.pipeline import DataConfig, SyntheticTokenPipeline
 from repro.ft.heartbeat import HeartbeatMonitor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.train.optimizer import AdamWConfig
 from repro.train.train_step import StepConfig, make_train_step
@@ -37,6 +38,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)

@@ -8,8 +8,11 @@ demultiplexes by lane id after `recv` (this mirrors how RAMC multiplexes
 logical channels over a single notified-access region: lanes are a typing
 discipline, not extra windows, so the O(1)-metadata property survives).
 
-Headers and payloads are stored bitcast into the queue's float32 cells, so
-int32/uint32/float32 payloads round-trip exactly.
+Headers and payloads are stored bitcast into the queue's uint32 cells, so
+int32/uint32/float32 payloads round-trip exactly.  The cells are integers
+on purpose: a small int32 header word bitcast to float32 is a denormal,
+which TPU float ops flush to zero.  With float32 cells the disaggregated
+engine stopped draining on four v5e chips.
 """
 
 from __future__ import annotations
@@ -76,7 +79,7 @@ class RecvBatch(NamedTuple):
     lane_id: Array   # [n] int32
     src: Array       # [n] int32
     tag: Array       # [n] int32
-    words: Array     # [n, max_payload_words] float32 raw payload cells
+    words: Array     # [n, max_payload_words] uint32 raw payload cells
     valid: Array     # [n] bool
 
 
@@ -110,8 +113,7 @@ class Channel:
         k = payload.shape[0]
         w = _lane_width(lane)
         flat = payload.reshape(k, w)
-        if jnp.dtype(lane.dtype) != jnp.dtype(jnp.float32):
-            flat = lax.bitcast_convert_type(flat.astype(lane.dtype), jnp.float32)
+        flat = lax.bitcast_convert_type(flat.astype(lane.dtype), jnp.uint32)
         pad = self.payload_words - w
         if pad < 0:
             raise ChannelError(f"lane {name!r} payload wider than channel item")
@@ -125,7 +127,7 @@ class Channel:
             ],
             axis=1,
         )
-        return jnp.concatenate([lax.bitcast_convert_type(hdr_i, jnp.float32), flat], axis=1)
+        return jnp.concatenate([lax.bitcast_convert_type(hdr_i, jnp.uint32), flat], axis=1)
 
     def homogeneous(self) -> bool:
         """Whether every lane shares one payload shape + dtype + kind — the
@@ -155,7 +157,7 @@ class Channel:
                 )
             hdr = hdr.at[:, 0].set(lane_id.astype(jnp.int32))
         return jnp.concatenate(
-            [lax.bitcast_convert_type(hdr, jnp.float32), msgs[:, HDR:]], axis=1
+            [lax.bitcast_convert_type(hdr, jnp.uint32), msgs[:, HDR:]], axis=1
         )
 
     def send(
@@ -188,9 +190,7 @@ class Channel:
                      mask: Array) -> tuple[Array, Array]:
         """Decode `batch` rows as `lane`-typed payloads, zeroing ~mask."""
         w = _lane_width(lane)
-        flat = batch.words[:, :w]
-        if jnp.dtype(lane.dtype) != jnp.dtype(jnp.float32):
-            flat = lax.bitcast_convert_type(flat, lane.dtype)
+        flat = lax.bitcast_convert_type(batch.words[:, :w], lane.dtype)
         flat = jnp.where(mask[:, None], flat, jnp.zeros_like(flat))
         return flat.reshape((batch.words.shape[0],) + lane.shape), mask
 
@@ -231,7 +231,7 @@ def channel_allocate(
     for lane in lanes:
         _check_dtype(lane.dtype)
     item_w = HDR + max(_lane_width(l) for l in lanes)
-    desc, state = rq.queue_allocate(mesh, axis, capacity, (item_w,), jnp.float32)
+    desc, state = rq.queue_allocate(mesh, axis, capacity, (item_w,), jnp.uint32)
     return Channel(lanes, desc), state
 
 
@@ -260,7 +260,7 @@ class HostChannel:
             (int(np.prod(l.shape)) if l.shape else 1) for l in self.lanes
         )
         self.group = rq.HostQueueGroup(p, capacity, HDR + self.payload_words,
-                                       np.float32, fabric=fabric, name=name)
+                                       np.uint32, fabric=fabric, name=name)
         self._pending: dict[int, list[tuple[int, np.ndarray]]] = {}
 
     def _lane_id(self, name: str) -> int:
@@ -274,9 +274,9 @@ class HostChannel:
         lid = self._lane_id(name)
         lane = self.lanes[lid]
         w = int(np.prod(lane.shape)) if lane.shape else 1
-        flat = np.asarray(payload, lane.dtype).reshape(w).view(np.float32)
-        row = np.zeros(HDR + self.payload_words, np.float32)
-        row[:HDR] = np.asarray([lid, src, tag, w], np.int32).view(np.float32)
+        flat = np.asarray(payload, lane.dtype).reshape(w).view(np.uint32)
+        row = np.zeros(HDR + self.payload_words, np.uint32)
+        row[:HDR] = np.asarray([lid, src, tag, w], np.int32).view(np.uint32)
         row[HDR : HDR + w] = flat
         self._pending.setdefault(src, []).append((dest, row))
 

@@ -56,7 +56,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.obs import causal as obs_causal
 from repro.obs import trace as obs_trace
 
@@ -209,7 +208,7 @@ def send(
     """
     desc = channel.desc
     axis = desc.axis
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     L = len(channel.lanes)
     me = lax.axis_index(axis)
     k = dest.shape[0]

@@ -48,7 +48,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro import compat
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import plan as plan_mod
@@ -199,7 +198,7 @@ def enqueue_epoch(
     ([p, *rider.shape] each).  `flow` uses this for credit-limit refreshes.
     """
     axis, cap = desc.axis, desc.capacity
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     me = lax.axis_index(axis)
     k = dest.shape[0]
     tr = obs_trace.TRACER
@@ -298,7 +297,7 @@ def enqueue_shift(
 ) -> tuple[QueueState, EnqueueReceipt]:
     """All k messages to rank (me+shift) mod p — the pipeline/ring special
     case the Pallas `queue_push` kernel implements with literal DMAs."""
-    p = compat.axis_size(desc.axis)
+    p = jax.lax.axis_size(desc.axis)
     me = lax.axis_index(desc.axis)
     dest = jnp.full((msgs.shape[0],), (me + shift) % p, jnp.int32)
     return enqueue(desc, state, msgs, dest)

@@ -48,7 +48,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import plan as plan_mod
 from repro.core import window as window_mod
 from repro.core.locks_sim import _AtomicWord
@@ -254,7 +253,7 @@ def ref_update_record(plan: plan_mod.RmaPlan, ids: Array, owner: Array,
                       delta: Array, axis: str):
     """Record one refcount round: (page id, delta) pairs fly to their owner
     as ONE fused a2a (the §2.4 slotted accumulate; kind ``accs``)."""
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     k = ids.shape[0]
     valid = (owner >= 0) & (owner < p) & (ids >= 0)
     owner_safe = jnp.where(valid, owner, 0).astype(jnp.int32)

@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core import plan as plan_mod
 
 from . import heap
@@ -282,7 +281,7 @@ def scatter_pages(axis: str, pool: Array, payload: Array, slot: Array,
     slots ride ONE fused a2a wire transfer (plan-aggregated), the owner
     scatters rows into its pool — the prefill → decoder-pool direct write.
     """
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     n_pages = pool.shape[0]
     S = slot.shape[0]
     flat = payload.reshape(S, -1).astype(pool.dtype)
@@ -320,7 +319,7 @@ def gather_pages(axis: str, pool: Array, entries: Array,
     requests zeroed.  Runs on all ranks (SPMD): ranks that want nothing
     send empty id lists but still serve replies from their pool.
     """
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     n_pages = pool.shape[0]
     m, ppb = entries.shape[0], entries.shape[1]
     S = m * ppb                                          # flat pull slots

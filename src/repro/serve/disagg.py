@@ -63,8 +63,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.kernels.common import interpret_mode
 from repro.kernels.paged_attention import kernel as pattn
 from repro.obs import causal as obs_causal
@@ -447,7 +447,7 @@ class DisaggEngine:
                               P(axis, None)),
                     out_specs=(qspecs, fspecs, pspec,
                                P(axis, None), P(axis, None),
-                               P(axis), P(axis, None)),
+                               P(axis), P(axis)),
                     check_vma=False,
                 )
             )
@@ -545,7 +545,7 @@ class DisaggEngine:
                               P(axis, None), P(axis, None)),
                     out_specs=(qspecs, fspecs, pspec,
                                P(axis, None, None, None), P(axis, None),
-                               P(axis, None), P(axis), P(axis, None)),
+                               P(axis, None), P(axis), P(axis)),
                     check_vma=False,
                 )
             )
@@ -583,7 +583,7 @@ class DisaggEngine:
                     in_specs=(P(), qspecs, fspecs, P(axis, None), P(axis),
                               P(axis), P(axis, None)),
                     out_specs=(qspecs, fspecs, P(axis, None), P(axis, None),
-                               P(axis), P(axis, None)),
+                               P(axis), P(axis)),
                     check_vma=False,
                 )
             )

@@ -9,8 +9,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core.rma import OpCounter
 from repro.rmaq import flow, queue as rq
 from repro.rmaq.channel import Lane
@@ -37,7 +37,8 @@ SHARE = CAP // (N_PROD * L)          # initial credits per (producer, lane)
 
 specs_in = (qspecs, fspecs, P("x", None, None), P("x", None), P("x", None),
             P("x", None))
-specs_out = (qspecs, fspecs, (P("x", None),) * 4, P("x", None))
+specs_out = (qspecs, fspecs, (P("x", None), P("x", None), P("x"), P("x")),
+             P("x", None))
 
 
 def mk_step(drain):

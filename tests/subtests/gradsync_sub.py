@@ -9,7 +9,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.parallel.compression import compress_decompress, init_compression_state

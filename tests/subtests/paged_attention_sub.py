@@ -10,9 +10,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.kernels.paged_attention import ops, ref
 from repro.kernels.paged_gather import ops as pg_ops
 
@@ -54,9 +55,12 @@ w = pt * 2 * hd
 y = ops.paged_attention_shift(q, kv, ids, shift, mesh, "x")
 rows = pg_ops.paged_gather(kv.reshape(n, n_pages, w), ids, shift, mesh, "x")
 rows = rows.reshape(n, k, pt, 2, hd)
-local_ids = jnp.broadcast_to(jnp.arange(k, dtype=jnp.int32), (n, k))
+local_ids = jnp.arange(k, dtype=jnp.int32)[None]
+# per-rank rows of the mesh-sharded results are read back on the host
+q_h, rows_h, y_h = (np.asarray(a) for a in (q, rows, y))
 for r in range(n):
-    yb = ops.paged_attention(q[r][None], rows[r], local_ids[r][None])[0]
-    err = float(jnp.max(jnp.abs(y[r] - yb)))
+    yb = ops.paged_attention(jnp.asarray(q_h[r][None]), jnp.asarray(rows_h[r]),
+                             local_ids)[0]
+    err = float(np.max(np.abs(y_h[r] - np.asarray(yb))))
     assert err < 1e-4, f"rank={r} err={err}"
 print(f"PASS streamed == paged_gather + local fused (shift={shift}, {n} ranks)")

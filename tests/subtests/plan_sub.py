@@ -8,8 +8,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core import dsde, rma
 from repro.core.plan import AccessEpoch, RmaPlan
 from repro.core.rma import OpCounter

@@ -5,7 +5,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import rma

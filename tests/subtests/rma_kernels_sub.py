@@ -1,7 +1,7 @@
 """Multi-device: RMA Pallas kernels (interpret mode) vs lax refs."""
 import functools
 import jax, jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.kernels.rma import ops, ref
 

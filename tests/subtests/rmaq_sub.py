@@ -6,8 +6,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core.rma import OpCounter
 from repro.kernels.rmaq import ops as kops, ref as kref
 from repro.rmaq import channel as rch, queue as rq
@@ -131,7 +131,7 @@ assert jnp.array_equal(sk, sr) and jnp.array_equal(nk, nr)
 bk2, ck2, sk2, nk2 = kops.queue_push(bk, ck, pmsgs, 1, mesh, "x")
 br2, cr2, sr2, nr2 = frq(br, cr, pmsgs)
 assert jnp.allclose(bk2, br2) and jnp.array_equal(ck2, cr2)
-assert jnp.array_equal(sk2, sr2) and int(sk2[0]) == 3
+assert jnp.array_equal(sk2, sr2) and int(np.asarray(sk2)[0]) == 3
 print("PASS pallas queue_push == xla ref (incl. backpressure)")
 
 # --------------------------------------------------------- channel multiplex

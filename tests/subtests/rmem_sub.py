@@ -11,8 +11,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core import plan as plan_mod
 from repro.core.rma import OpCounter
 from repro.kernels.paged_gather import ops as pg_ops, ref as pg_ref
@@ -84,7 +84,7 @@ def rel_step(s, ids_in, owner):
 
 f_rel = jax.jit(sm(rel_step,
                    in_specs=(specs, P("x", None, None), P("x", None)),
-                   out_specs=(specs, P("x", None))))
+                   out_specs=(specs, P("x"))))
 
 
 def share_step(s, ids_in, owner, delta):
@@ -98,7 +98,7 @@ def share_step(s, ids_in, owner, delta):
 f_share = jax.jit(sm(share_step,
                      in_specs=(specs, P("x", None, None), P("x", None),
                                P("x", None)),
-                     out_specs=(specs, P("x", None))))
+                     out_specs=(specs, P("x"))))
 
 delta_p1 = np.ones((N, N * KMAX), np.int32)
 st, nf = f_share(st, jnp.asarray(ids), jnp.asarray(flat_owner),

@@ -15,8 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core import locks_sim
 from repro.rmem import heap
 

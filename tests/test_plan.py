@@ -7,8 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core import plan as plan_mod
 from repro.core import rma
 from repro.core.epoch import SyncStats, flush, flush_local

@@ -19,8 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core import plan as plan_mod
 from repro.core.plan import RmaPlan
 from repro.core.rma import OpCounter
@@ -64,7 +64,7 @@ class TestCodecRoundTrip:
     @given(st.integers(0, 10_000), st.sampled_from(DTYPES_64))
     def test_roundtrip_64bit_payloads(self, seed, dtype_name):
         """64-bit payloads split into two words losslessly (x64 scope)."""
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             rng = np.random.RandomState(seed)
             x = _sample(rng, dtype_name, (3, 4))
             assert jnp.dtype(x.dtype).itemsize == 8

@@ -213,6 +213,36 @@ class TestQueueMetadata:
         assert c1.metadata_nbytes() == c2.metadata_nbytes()
 
 
+@pytest.mark.parametrize("name,payload", [
+    ("ids", [[3, -1]]),
+    ("kv", [[1e-45, -2.5]]),      # a float32 denormal payload word
+])
+def test_channel_cells_are_integers(name, payload):
+    """Channel cells are uint32: a TPU flushes float32 denormals to zero,
+    and a small int32 header word bitcast to float32 is one."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.rmaq import channel as rch
+
+    mesh = jax.make_mesh((1,), ("w",))
+    ch, state = rch.channel_allocate(
+        mesh, "w", 8,
+        [rch.Lane("ids", (2,), jnp.int32), rch.Lane("kv", (2,), jnp.float32)])
+    assert state.buf.dtype == jnp.uint32
+    want = np.asarray(payload, ch.lane(name).dtype)
+    msgs = ch.pack(name, jnp.asarray(want), jnp.asarray([7], jnp.int32))
+    assert msgs.dtype == jnp.uint32
+    hdr = np.asarray(msgs[:, :rch.HDR]).view(np.int32)
+    batch = rch.RecvBatch(lane_id=jnp.asarray(hdr[:, 0]), src=jnp.asarray(hdr[:, 1]),
+                          tag=jnp.asarray(hdr[:, 2]), words=msgs[:, rch.HDR:],
+                          valid=jnp.asarray([True]))
+    got, mask = ch.payload(batch, name)
+    assert bool(mask[0]) and int(batch.tag[0]) == 7
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  want.view(np.uint32))
+
+
 # ----------------------------------------------------- multi-device subtests
 def test_rmaq_spmd_xla_and_pallas_paths():
     run_subtest("rmaq_sub.py", devices=4)

@@ -207,7 +207,7 @@ class TestFabricDiff:
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.core.rma import OpCounter
         from repro.rmaq import queue as rq
 

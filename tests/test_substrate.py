@@ -210,9 +210,7 @@ class TestHloCost:
         analytic = 8 * 2 * 16 * 64 * 64
         assert 0.9 * analytic < s.flops < 2.0 * analytic, s.flops
         # XLA's own counter must be ~1/8 of ours (loop counted once)
-        from repro.compat import cost_analysis
-
-        xla = cost_analysis(c)["flops"]
+        xla = c.cost_analysis()["flops"]
         assert s.flops > 4 * xla
 
     def test_dot_flops_exact_without_loops(self):

@@ -1,0 +1,96 @@
+"""Compile checks for a TPU v5e, made without a chip.
+
+The TPU compiler compiles for a described `v5e:2x2` topology: what it
+refuses here (unaligned tiles, too much fast memory, a program that does
+not fit the device) would fail on the chip.  Nothing runs, so these say
+nothing about results or times.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process at a time may load the TPU library,
+and under pytest-xdist every worker imports every test file.  Keep these
+tests in this one file, so that one worker loads the library.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.paged_attention.kernel import paged_attention_pallas
+from repro.kernels.rma.kernel import put_shift_pallas
+from repro.models import build_model
+
+HBM_BYTES = 16 * 10**9        # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "no TPU compiler"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)
+
+
+def test_smollm_decode_step_fits_one_chip(one_chip):
+    """Full-width SmolLM-360M decode step at the serving shape of
+    `chip_smoke.py` (4 slots, max_seq 512)."""
+    model = build_model(get_config("smollm-360m"))
+    params = _on(one_chip, model.init_shapes())
+    cache = _on(one_chip, jax.eval_shape(lambda: model.init_cache(4, 512)))
+    token = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(model.decode_step).lower(params, token, cache).compile()
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert 0 < used < HBM_BYTES, used
+
+
+@pytest.mark.parametrize("pt,hd,n_pages,m,k", [
+    (4, 32, 48, 4, 4),        # the disaggregated engine's pages
+    (16, 128, 64, 8, 8),
+])
+def test_paged_attention_compiles(one_chip, pt, hd, n_pages, m, k):
+    q = jax.ShapeDtypeStruct((m, 1, hd), jnp.float32, sharding=one_chip)
+    pages = jax.ShapeDtypeStruct((n_pages, pt, 2, hd), jnp.float32,
+                                 sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((m, k), jnp.int32, sharding=one_chip)
+    fn = functools.partial(paged_attention_pallas, scale=1.0, causal=False,
+                           interpret=False)
+    text = jax.jit(fn).lower(q, pages, ids).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_put_shift_compiles_over_four_chips(topo):
+    """The compiled ring put the plan picks on a TPU for >= ~300 KB
+    (8,128)-tileable payloads, in shard_map over the four chips."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("x",))
+    spec = P("x", None)
+    x = jax.ShapeDtypeStruct((4 * 1024, 256), jnp.float32,
+                             sharding=NamedSharding(mesh, spec))
+    put = functools.partial(put_shift_pallas, shift=1, axis="x", n=4,
+                            interpret=False)
+    fn = jax.jit(shard_map(put, mesh=mesh, in_specs=spec, out_specs=spec,
+                           check_vma=False))
+    assert "tpu_custom_call" in fn.lower(x).compile().as_text()
