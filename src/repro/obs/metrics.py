@@ -19,6 +19,10 @@ The shared schema is the snapshots' own key naming — `raw_msgs` /
 `Fabric.snapshot()` (the latter prefixes sync fields with ``sync_``), so
 `ingest` needs no per-source adapters.  `snapshot_delta` is the common
 implementation behind each ledger's `delta(prev)` helper.
+
+`REGISTRY` is the process-wide default, the one an operator's scrape reads:
+`ServeEngine` counts its host gaps and compiles there unless it is handed a
+registry of its own.
 """
 
 from __future__ import annotations
@@ -209,3 +213,8 @@ class MetricsRegistry:
             else:
                 out[full] = m.value
         return out
+
+
+# The process-wide registry: components that take a `metrics=` argument
+# default to it, and tests hand them a fresh `MetricsRegistry` instead.
+REGISTRY = MetricsRegistry()

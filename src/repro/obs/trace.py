@@ -45,6 +45,14 @@ point in time, whereas a span covers an interval and its `set()` calls can
 land at any moment inside it.  `Tracer.span` raises ``ValueError`` on
 them so a stitching bug fails loudly at the producer, not as a silently
 disconnected DAG at analysis time.
+
+**Spans the device trace sees.**  `profiled_span(name)` is the one entry
+point for host phases that must line up with the device's timeline: it
+always opens a `jax.profiler.TraceAnnotation(name)`, which lands in the
+profiler's trace (on the calling thread's host line) when a profile is
+recording and costs one native call otherwise, and when a recording tracer
+is installed it also opens that tracer's `Span` with the same name and
+attrs.  The annotation carries the bare name: attrs go to the tracer only.
 """
 
 from __future__ import annotations
@@ -146,6 +154,56 @@ class Span:
     def __exit__(self, *exc) -> bool:
         self.close()
         return False
+
+
+# `jax.profiler.TraceAnnotation`, bound on the first profiled span, so a
+# module that imports the tracer (the simulator's do) does not import JAX
+# through it.
+_TraceAnnotation = None
+
+
+def _bind_trace_annotation():
+    global _TraceAnnotation
+    from jax.profiler import TraceAnnotation
+
+    _TraceAnnotation = TraceAnnotation
+    return TraceAnnotation
+
+
+class _ProfiledSpan:
+    """A profiler annotation and a recording tracer's span, opened and
+    closed together; entered, it hands back the tracer's span."""
+
+    __slots__ = ("_annotation", "_span")
+
+    def __init__(self, annotation, span: Span):
+        self._annotation = annotation
+        self._span = span
+
+    def __enter__(self) -> Span:
+        self._annotation.__enter__()
+        return self._span.__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        self._span.__exit__(*exc)
+        self._annotation.__exit__(*exc)
+        return False
+
+
+def profiled_span(name: str, rank: int = 0, **attrs):
+    """Open a host span that the profiler's device trace can see.
+
+    Use as ``with profiled_span("serve.step"):``.  With the default
+    `NullTracer` this is a bare `TraceAnnotation(name)` and `attrs` are
+    dropped unread; with a recording tracer installed, the tracer's span
+    (same name, `rank` and attrs) is opened inside the annotation and is
+    what ``as`` binds.  Disabled, ``as`` binds the annotation, so only code
+    that installed a recording tracer itself may use the bound span."""
+    annotation = _TraceAnnotation or _bind_trace_annotation()
+    tr = TRACER
+    if not tr.enabled:
+        return annotation(name)
+    return _ProfiledSpan(annotation(name), tr.span(name, rank, **attrs))
 
 
 class Tracer:

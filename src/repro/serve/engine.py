@@ -31,6 +31,31 @@ Slots are fixed (static shapes); finished lanes are recycled.
 `run_until_drained` loops it, raising `DrainError` (with the undrained
 request ids) instead of silently returning partial results when `max_steps`
 is exhausted.
+
+Host phases are spans through `obs.trace.profiled_span`, so a profiler
+trace names what the host did in each device idle gap (DESIGN.md §12.1):
+`serve.admit` holds `serve.prefill.prepare`/`.launch`/`.readback` per
+admitted request; `serve.step` holds `serve.decode.prepare`/`.launch`/
+`.readback`/`.emit`; `serve.recycle` is the exclusive recycle section on
+either path.
+
+Counters (in `obs.metrics.REGISTRY` unless the engine is handed a registry),
+per program, `decode` or `prefill`:
+
+  * ``serve.<program>.host_gap_s`` / ``.host_gaps`` — the seconds and number
+    of host gaps ended by a dispatch of that program.  A host gap runs from
+    the moment the engine's last device result reached the host (the return
+    of the token read-back in `step()` or `admit()`) to the return of the
+    next dispatch: the engine's emit and recycle, the caller's own loop
+    between `schedule()` calls, the empty admission check, preparation and
+    the dispatch itself.  It is counted only while the engine holds work: a
+    read-back that leaves no busy lane and an empty queue opens none, so
+    the time between drained batches is not a host gap.  A gap whose ending
+    dispatch compiled is not counted either.
+  * ``serve.compiles{program=}`` / ``serve.compile_s{program=}`` — dispatches
+    that added an entry to that jitted program's cache (each new prompt
+    length compiles a prefill), and their seconds: trace, lower, and compile
+    or load from the persistent cache.
 """
 
 from __future__ import annotations
@@ -48,8 +73,9 @@ import numpy as np
 from repro.core.locks_sim import WRITER_BIT, LockOrigin, LockWindow
 from repro.models.registry import Model
 from repro.obs import flight as obs_flight
+from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
-from repro.obs.metrics import MetricsRegistry
+from repro.obs.trace import profiled_span
 
 
 class LockDisciplineError(RuntimeError):
@@ -91,8 +117,23 @@ class Request:
     t_submit: float = 0.0      # wall time of submit() (TTFT reference point)
 
 
+class _ProgramCounters:
+    """The host-gap and compile counters of one jitted program, looked up
+    once so that a dispatch costs no registry lookup."""
+
+    __slots__ = ("jitted", "gap_s", "gaps", "compiles", "compile_s")
+
+    def __init__(self, registry: obs_metrics.MetricsRegistry, program: str, jitted):
+        self.jitted = jitted
+        self.gap_s = registry.counter(f"serve.{program}.host_gap_s")
+        self.gaps = registry.counter(f"serve.{program}.host_gaps")
+        self.compiles = registry.counter("serve.compiles", program=program)
+        self.compile_s = registry.counter("serve.compile_s", program=program)
+
+
 class ServeEngine:
-    def __init__(self, model: Model, params, n_slots: int = 4, max_seq: int = 256):
+    def __init__(self, model: Model, params, n_slots: int = 4, max_seq: int = 256,
+                 metrics: Optional[obs_metrics.MetricsRegistry] = None):
         self.model = model
         self.params = params
         self.n_slots = n_slots
@@ -113,12 +154,14 @@ class ServeEngine:
         # host stand-in for window-region disjointness (see module docstring)
         self._cache_mu = threading.Lock()
         self.recycled_total = 0
-        # request-lifecycle latency ledgers (§12): TTFT = submit -> first
-        # token; TBT = gap between a lane's consecutive token emissions
-        self.metrics = MetricsRegistry()
-        self._slot_t_last = [0.0] * n_slots
         self._decode = jax.jit(model.decode_step)
         self._prefill = jax.jit(self._prefill_impl, static_argnames=("plen",))
+        registry = obs_metrics.REGISTRY if metrics is None else metrics
+        self._decode_counters = _ProgramCounters(registry, "decode", self._decode)
+        self._prefill_counters = _ProgramCounters(registry, "prefill", self._prefill)
+        # perf_counter() when the last device result reached the host, or
+        # None when no host gap is open (module docstring)
+        self._t_result: Optional[float] = None
 
     # --------------------------------------------------------- plumbing
     def _prefill_impl(self, params, cache, tokens, slot, plen):
@@ -138,6 +181,26 @@ class ServeEngine:
         new_cache = jax.tree.map(put, cache, lane_cache)
         new_cache["len"] = cache["len"]  # global len unused in slot mode
         return logits[0], new_cache
+
+    def _launch(self, fn, counters: _ProgramCounters, *args, **kwargs):
+        """Dispatch one device program and count what its return ends: the
+        open host gap, or a compile.  Called under `_cache_mu`, which keeps
+        the counters' updates from interleaving."""
+        size = counters.jitted._cache_size()
+        t0 = time.perf_counter()
+        out = fn(*args, **kwargs)
+        t1 = time.perf_counter()
+        if counters.jitted._cache_size() != size:
+            counters.compiles.inc()
+            counters.compile_s.inc(t1 - t0)
+        elif self._t_result is not None:
+            counters.gaps.inc()
+            counters.gap_s.inc(t1 - self._t_result)
+        self._t_result = None
+        return out
+
+    def _holds_work(self) -> bool:
+        return not self.queue.empty() or not all(self.slot_free)
 
     def submit(self, req: Request) -> None:
         req.t_submit = time.perf_counter()
@@ -195,112 +258,105 @@ class ServeEngine:
         the §2.3 fix: the old code mutated the slot table (and signalled
         `done`) while holding only the reader lock.
         """
-        admitted = 0
-        while True:
-            claim = self._alloc_slot()
-            if claim is None:
-                return admitted
-            req, slot = claim
-            t_admit = time.perf_counter()
-            self.metrics.histogram("seg.queue_wait_us").observe(
-                (t_admit - req.t_submit) * 1e6)
-            tr = obs_trace.TRACER
-            if tr.enabled:
-                # seg milestones cut the TTFT interval (obs.critpath): the
-                # time since the previous milestone — here, since submit —
-                # is charged to the named segment
-                tr.event("serve.request.admit", rid=req.rid, slot=slot,
-                         seg="queue_wait")
-            with self.lock.shared(0):
-                plen = len(req.prompt)
+        with profiled_span("serve.admit"):
+            admitted = 0
+            while True:
+                claim = self._alloc_slot()
+                if claim is None:
+                    return admitted
+                req, slot = claim
+                self._admit_one(req, slot)
+                admitted += 1
+
+    def _admit_one(self, req: Request, slot: int) -> None:
+        """Prefill one claimed lane and emit the request's first token."""
+        tr = obs_trace.TRACER
+        if tr.enabled:
+            # seg milestones cut the TTFT interval (obs.critpath): the
+            # time since the previous milestone — here, since submit —
+            # is charged to the named segment
+            tr.event("serve.request.admit", rid=req.rid, slot=slot,
+                     seg="queue_wait")
+        with self.lock.shared(0):
+            plen = len(req.prompt)
+            with profiled_span("serve.prefill.prepare"):
                 tokens = jnp.zeros((self.max_seq,), jnp.int32).at[:plen].set(
                     jnp.asarray(req.prompt, jnp.int32)
                 )
-                with self._cache_mu:
-                    logits, self.cache = self._prefill(
-                        self.params, self.cache, tokens, slot, plen=plen
-                    )
-                self.slot_pos[slot] = plen
-                first = int(jnp.argmax(logits))
-                self.slot_last[slot] = first
-                req.output.append(first)   # the prefill already produced token 1
-                now = time.perf_counter()
-                # exemplar=rid: the p99 summary names a concrete request
-                # whose causal DAG explains the tail (obs.metrics)
-                self.metrics.histogram("serve.ttft_us").observe(
-                    (now - req.t_submit) * 1e6, exemplar=req.rid
+            with profiled_span("serve.prefill.launch"), self._cache_mu:
+                logits, self.cache = self._launch(
+                    self._prefill, self._prefill_counters,
+                    self.params, self.cache, tokens, slot, plen=plen
                 )
-                self.metrics.histogram("seg.prefill_us").observe(
-                    (now - t_admit) * 1e6)
-                self._slot_t_last[slot] = now
-                tr = obs_trace.TRACER
-                if tr.enabled:
-                    tr.event("serve.request.prefill", rid=req.rid, slot=slot,
-                             plen=plen, seg="prefill")
-                    tr.event("serve.request.first_token", rid=req.rid,
-                             slot=slot, seg="host",
-                             ttft_us=int((now - req.t_submit) * 1e6))
-                if len(req.output) < req.max_new:
-                    # decode may pick the lane up now; an instantly-finished
-                    # request must never become visible to the decoder (the
-                    # scheduler could emit an extra token — or recycle the
-                    # lane before our exclusive recycle below runs)
-                    self.slot_ready[slot] = True
-            if len(req.output) >= req.max_new:
-                with self.lock.exclusive(0):
-                    self._recycle(slot)
-            admitted += 1
+            self.slot_pos[slot] = plen
+            with profiled_span("serve.prefill.readback"):
+                first = int(jnp.argmax(logits))
+            self._t_result = time.perf_counter()
+            self.slot_last[slot] = first
+            req.output.append(first)   # the prefill already produced token 1
+            tr = obs_trace.TRACER
+            if tr.enabled:
+                ttft_us = int((time.perf_counter() - req.t_submit) * 1e6)
+                tr.event("serve.request.prefill", rid=req.rid, slot=slot,
+                         plen=plen, seg="prefill")
+                tr.event("serve.request.first_token", rid=req.rid,
+                         slot=slot, seg="host", ttft_us=ttft_us)
+            if len(req.output) < req.max_new:
+                # decode may pick the lane up now; an instantly-finished
+                # request must never become visible to the decoder (the
+                # scheduler could emit an extra token — or recycle the
+                # lane before our exclusive recycle below runs)
+                self.slot_ready[slot] = True
+        if len(req.output) >= req.max_new:
+            with profiled_span("serve.recycle"), self.lock.exclusive(0):
+                self._recycle(slot)
+            if not self._holds_work():
+                self._t_result = None
 
     def step(self) -> int:
         """One decode step over all active lanes; returns #tokens emitted."""
-        with self.lock.shared(0):
-            active = [i for i in range(self.n_slots)
-                      if not self.slot_free[i] and self.slot_ready[i]]
-            if not active:
-                return 0
-            tokens = jnp.asarray(self.slot_last, jnp.int32)
-            # the cache len is per-engine-step: use max position (static
-            # shapes); per-slot masking comes from kv_valid_len in attention
-            with self._cache_mu:
-                cache = dict(self.cache)
-                cache["len"] = jnp.asarray(int(self.slot_pos.max()), jnp.int32)
-                logits, new_cache = self._decode(self.params, tokens, cache)
-                self.cache = new_cache
-            emitted = 0
-            finished = []
-            nxt = np.asarray(jnp.argmax(logits, -1))
-            tbt_hist = self.metrics.histogram("serve.tbt_us")
-            for i in active:
-                req = self.slot_req[i]
-                if req is None:            # recycled concurrently mid-step
-                    continue
-                req.output.append(int(nxt[i]))
-                self.slot_last[i] = int(nxt[i])
-                self.slot_pos[i] += 1
-                now = time.perf_counter()
-                tbt_hist.observe((now - self._slot_t_last[i]) * 1e6)
-                self._slot_t_last[i] = now
-                emitted += 1
-                if len(req.output) >= req.max_new or self.slot_pos[i] >= self.max_seq - 1:
-                    finished.append(i)
-        if finished:
-            # exclusive-lock section: recycle the finished lanes
-            with self.lock.exclusive(0):
-                for i in finished:
-                    self._recycle(i)
-        return emitted
-
-    def serve_metrics(self) -> dict:
-        """Request-latency summaries (§12): TTFT and TBT in microseconds,
-        plus the per-segment TTFT decomposition (§15)."""
-        return {
-            "ttft_us": self.metrics.histogram("serve.ttft_us").summary(),
-            "tbt_us": self.metrics.histogram("serve.tbt_us").summary(),
-            "seg.queue_wait_us":
-                self.metrics.histogram("seg.queue_wait_us").summary(),
-            "seg.prefill_us":
-                self.metrics.histogram("seg.prefill_us").summary(),
-        }
+        with profiled_span("serve.step"):
+            with self.lock.shared(0):
+                with profiled_span("serve.decode.prepare"):
+                    active = [i for i in range(self.n_slots)
+                              if not self.slot_free[i] and self.slot_ready[i]]
+                    if not active:
+                        return 0
+                    tokens = jnp.asarray(self.slot_last, jnp.int32)
+                    # the cache len is per-engine-step: use max position
+                    # (static shapes); per-slot masking comes from
+                    # kv_valid_len in attention
+                    cache_len = jnp.asarray(int(self.slot_pos.max()), jnp.int32)
+                # the cache dict is copied under the mutex: an admitting
+                # thread may swap `self.cache` until we hold it
+                with profiled_span("serve.decode.launch"), self._cache_mu:
+                    cache = dict(self.cache)
+                    cache["len"] = cache_len
+                    logits, self.cache = self._launch(
+                        self._decode, self._decode_counters, self.params, tokens, cache)
+                with profiled_span("serve.decode.readback"):
+                    nxt = np.asarray(jnp.argmax(logits, -1))
+                self._t_result = time.perf_counter()
+                emitted = 0
+                finished = []
+                with profiled_span("serve.decode.emit"):
+                    for i in active:
+                        req = self.slot_req[i]
+                        if req is None:            # recycled concurrently mid-step
+                            continue
+                        req.output.append(int(nxt[i]))
+                        self.slot_last[i] = int(nxt[i])
+                        self.slot_pos[i] += 1
+                        emitted += 1
+                        if len(req.output) >= req.max_new or self.slot_pos[i] >= self.max_seq - 1:
+                            finished.append(i)
+            if finished:
+                with profiled_span("serve.recycle"), self.lock.exclusive(0):
+                    for i in finished:
+                        self._recycle(i)
+                if not self._holds_work():
+                    self._t_result = None
+            return emitted
 
     def schedule(self) -> ScheduleTick:
         """One unified scheduler tick: admit, decode, recycle."""
@@ -322,7 +378,7 @@ class ServeEngine:
         drained engine.
         """
         steps = 0
-        while not self.queue.empty() or any(not f for f in self.slot_free):
+        while self._holds_work():
             if steps >= max_steps:
                 err = DrainError(
                     f"not drained after {max_steps} steps", self._undrained_rids()

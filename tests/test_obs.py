@@ -412,23 +412,62 @@ class TestDriftHarness:
 
 
 # ========================================================== serve latency
+SERVE_SPANS = (
+    "serve.admit", "serve.prefill.prepare", "serve.prefill.launch",
+    "serve.prefill.readback", "serve.step", "serve.decode.prepare",
+    "serve.decode.launch", "serve.decode.readback", "serve.decode.emit",
+    "serve.recycle",
+)
+
+
+def _stub_engine(n_slots=2, registry=None):
+    from repro.serve.engine import ServeEngine
+
+    from .test_training import _StubServeModel
+
+    return ServeEngine(_StubServeModel(), {}, n_slots=n_slots, max_seq=32,
+                       metrics=MetricsRegistry() if registry is None else registry)
+
+
+def _serve_stub() -> int:
+    """Drain three requests through a stub-model engine; returns the number
+    of `schedule()` calls.  Every engine phase runs at least once."""
+    from repro.serve.engine import Request
+
+    eng = _stub_engine()
+    for i in range(3):
+        eng.submit(Request(rid=i, prompt=[1, 2, 3], max_new=3))
+    return eng.run_until_drained()
+
+
 class TestServeLatencyMetrics:
     def test_engine_ttft_tbt_histograms(self):
-        from repro.serve.engine import Request, ServeEngine
+        """What the engine records of a drained batch: its ten spans, its
+        host-gap and compile counters, and the `serve.request.*` events from
+        which `obs.critpath` cuts TTFT (requests are timed by their client)."""
+        from repro.serve.engine import Request
 
-        from .test_training import _StubServeModel
-
-        eng = ServeEngine(_StubServeModel(), {}, n_slots=2, max_seq=32)
+        reg = MetricsRegistry()
+        eng = _stub_engine(registry=reg)
         with Tracer() as tr:
             reqs = [Request(rid=i, prompt=[1, 2], max_new=4) for i in range(3)]
             for r in reqs:
                 eng.submit(r)
-            eng.run_until_drained()
-        m = eng.serve_metrics()
-        assert m["ttft_us"]["count"] == 3          # one first-token per request
-        assert m["ttft_us"]["p50"] > 0
-        # 4 tokens per request, first from prefill: 3 decode gaps each
-        assert m["tbt_us"]["count"] == 9
+            steps = eng.run_until_drained()
+        assert {e["name"] for e in tr.events if e["ph"] == "X"} == set(SERVE_SPANS)
+        assert len(tr.named("serve.step")) == len(tr.named("serve.admit")) == steps
+        # two lanes: r0 and r1 prefill, decode 3 ticks and recycle, then r2
+        # prefills and decodes 3 ticks.  Each program compiles on its first
+        # dispatch; every later dispatch follows a read-back while the
+        # engine holds work, r2's prefill included (the queue held it).
+        c = reg.flat()
+        assert c["serve.compiles{program=prefill}"] == 1
+        assert c["serve.compiles{program=decode}"] == 1
+        assert c["serve.compile_s{program=decode}"] > 0
+        assert c["serve.prefill.host_gaps"] == 2
+        assert c["serve.decode.host_gaps"] == 5
+        assert c["serve.prefill.host_gap_s"] > 0 and c["serve.decode.host_gap_s"] > 0
+        assert [len(r.output) for r in reqs] == [4, 4, 4]
         assert len(tr.named("serve.request.submit")) == 3
         assert len(tr.named("serve.request.first_token")) == 3
         assert len(tr.named("serve.request.drain")) == 3
@@ -447,6 +486,189 @@ class TestServeLatencyMetrics:
         assert {"serve.request.submit", "serve.request.first_token",
                 "serve.request.drain"} <= names
         assert doc["metadata"]["clock_domain"] == "wall_us"
+
+
+# =========================================== engine host-gap / compile counters
+class TestEngineCounters:
+    def test_default_registry_is_the_process_wide_one(self):
+        from repro.obs.metrics import REGISTRY
+        from repro.serve.engine import ServeEngine
+
+        from .test_training import _StubServeModel
+
+        eng = ServeEngine(_StubServeModel(), {}, n_slots=1, max_seq=32)
+        assert eng._decode_counters.gaps is REGISTRY.counter("serve.decode.host_gaps")
+        assert eng._prefill_counters.compile_s is REGISTRY.counter(
+            "serve.compile_s", program="prefill")
+
+    def test_one_prefill_compile_per_prompt_length(self):
+        from repro.serve.engine import Request
+
+        reg = MetricsRegistry()
+        eng = _stub_engine(registry=reg)
+        for i in range(2):
+            eng.submit(Request(rid=i, prompt=[1, 2, 3], max_new=2))
+        eng.run_until_drained()
+        c = reg.flat()
+        assert c["serve.compiles{program=prefill}"] == 1
+        assert c["serve.compiles{program=decode}"] == 1
+        eng.submit(Request(rid=2, prompt=[1, 2, 3, 4, 5], max_new=2))
+        eng.run_until_drained()
+        c = reg.flat()
+        assert c["serve.compiles{program=prefill}"] == 2
+        assert c["serve.compiles{program=decode}"] == 1
+        assert c["serve.compile_s{program=prefill}"] > 0
+
+    def test_a_gap_that_ends_in_a_compile_is_not_counted(self):
+        """r1's prefill (a new length) and the first decode both follow a
+        read-back with work held, and both compile: neither counts a gap."""
+        from repro.serve.engine import Request
+
+        reg = MetricsRegistry()
+        eng = _stub_engine(registry=reg)
+        eng.submit(Request(rid=0, prompt=[1, 2], max_new=3))
+        eng.submit(Request(rid=1, prompt=[1, 2, 3], max_new=3))
+        tick = eng.schedule()
+        assert (tick.admitted, tick.emitted) == (2, 2)
+        c = reg.flat()
+        assert c["serve.compiles{program=prefill}"] == 2
+        assert c["serve.compiles{program=decode}"] == 1
+        assert c["serve.prefill.host_gaps"] == c["serve.decode.host_gaps"] == 0
+        assert c["serve.prefill.host_gap_s"] == c["serve.decode.host_gap_s"] == 0
+        eng.schedule()          # a decode that follows a read-back: counted
+        assert reg.flat()["serve.decode.host_gaps"] == 1
+
+    def test_first_dispatch_after_a_drain_counts_no_gap(self):
+        """Whatever the caller does between drained batches is not a host
+        gap: the next batch's first dispatch counts nothing."""
+        import time
+
+        from repro.serve.engine import Request
+
+        reg = MetricsRegistry()
+        eng = _stub_engine(registry=reg)
+        eng.submit(Request(rid=0, prompt=[1, 2], max_new=2))
+        eng.run_until_drained()
+        before = reg.flat()
+        time.sleep(0.2)
+        eng.submit(Request(rid=1, prompt=[1, 2], max_new=1))   # prefill only
+        eng.run_until_drained()
+        after = reg.flat()
+        assert after == before
+
+    def test_host_gaps_count_dispatches_after_a_readback_with_work_held(self):
+        """Over waves drained one by one, every dispatch that did not compile
+        counts one gap, except each later wave's first, which follows a
+        drained engine; the caller's pauses between waves land in none."""
+        import time
+
+        from repro.serve.engine import Request
+
+        reg = MetricsRegistry()
+        eng = _stub_engine(registry=reg)
+        calls = {"prefill": 0, "decode": 0}
+
+        def counted(program, fn):
+            def call(*a, **k):
+                calls[program] += 1
+                return fn(*a, **k)
+            return call
+
+        eng._prefill = counted("prefill", eng._prefill)
+        eng._decode = counted("decode", eng._decode)
+        waves = 3
+        for w in range(waves):
+            for i in range(2):
+                eng.submit(Request(rid=2 * w + i, prompt=[w + 1, 2], max_new=2 + 3 * i))
+            eng.run_until_drained()
+            time.sleep(0.1)
+        c = reg.flat()
+        assert calls["prefill"] == 2 * waves and calls["decode"] == 4 * waves
+        assert c["serve.prefill.host_gaps"] == (
+            calls["prefill"] - c["serve.compiles{program=prefill}"] - (waves - 1))
+        assert c["serve.decode.host_gaps"] == (
+            calls["decode"] - c["serve.compiles{program=decode}"])
+        assert c["serve.prefill.host_gap_s"] + c["serve.decode.host_gap_s"] < 0.1
+
+
+# ======================================== engine spans on the profiler's clock
+class TestProfiledSpans:
+    def test_disabled_span_is_a_bare_annotation(self):
+        import jax
+
+        assert not obs_trace.TRACER.enabled
+        sp = obs_trace.profiled_span("serve.step", rank=2, k=1)
+        assert isinstance(sp, jax.profiler.TraceAnnotation)
+        with sp:
+            pass
+
+    def test_recording_tracer_gets_the_span_with_its_attrs(self):
+        with Tracer() as tr:
+            with obs_trace.profiled_span("outer", rank=1, k=2) as sp:
+                with obs_trace.profiled_span("inner", rank=1):
+                    pass
+                sp.set(n=3)
+            with pytest.raises(ValueError, match="reserved causal attrs"):
+                obs_trace.profiled_span("s", edge="1:hop")
+        inner, outer = tr.named("inner")[0], tr.named("outer")[0]
+        assert outer["args"] == {"k": 2, "n": 3} and outer["rank"] == 1
+        assert outer["ts"] <= inner["ts"]
+        assert outer["ts"] + outer["dur"] >= inner["ts"] + inner["dur"]
+
+    def test_engine_spans_land_in_the_profiler_trace(self, tmp_path):
+        """Profile a stub-model engine as the benchmark does (no Python
+        tracer): every engine span sits on the line of the thread that
+        called `schedule()`, inside that thread's own annotation, with the
+        prefill phases inside `serve.admit` and the decode phases inside
+        `serve.step`."""
+        import glob
+        import os
+
+        import jax
+        from jax.profiler import ProfileData
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.enable_hlo_proto = False
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with jax.profiler.TraceAnnotation("test.scheduler"):
+                steps = _serve_stub()
+        finally:
+            jax.profiler.stop_trace()
+        path, = glob.glob(os.path.join(tmp_path, "plugins", "profile", "*", "*.xplane.pb"))
+        lines = [ln for p in ProfileData.from_file(path).planes
+                 if p.name.startswith("/host:") for ln in p.lines
+                 if any(ev.name == "test.scheduler" for ev in ln.events)]
+        assert len(lines) == 1
+        evs = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+               for ev in lines[0].events]
+        (lo, hi), = [(s, e) for n, s, e in evs if n == "test.scheduler"]
+        spans = {n: [(s, e) for m, s, e in evs if m == n] for n in SERVE_SPANS}
+        assert all(spans.values()), {n: len(v) for n, v in spans.items()}
+        assert all(lo <= s <= e <= hi for v in spans.values() for s, e in v)
+        assert len(spans["serve.admit"]) == len(spans["serve.step"]) == steps
+        assert len(spans["serve.prefill.launch"]) == 3
+
+        def inside(child, parent):
+            return all(any(ps <= s and e <= pe for ps, pe in spans[parent])
+                       for s, e in spans[child])
+
+        for name in SERVE_SPANS:
+            if name.startswith("serve.prefill."):
+                assert inside(name, "serve.admit"), name
+            if name.startswith("serve.decode."):
+                assert inside(name, "serve.step"), name
+
+    def test_recording_tracer_records_the_engine_spans(self):
+        with Tracer() as tr:
+            steps = _serve_stub()
+        recorded = {e["name"] for e in tr.events if e["ph"] == "X"}
+        assert recorded == set(SERVE_SPANS)
+        assert len(tr.named("serve.step")) == len(tr.named("serve.admit")) == steps
+        assert len(tr.named("serve.prefill.readback")) == 3
+        # each request finishes once: by one recycle on either path
+        assert len(tr.named("serve.request.drain")) == 3
 
 
 # ===================================================== attend-step latency
