@@ -61,12 +61,12 @@ def _batch_specs(batch_sds, policy):
 
 def _cache_specs(cache_sds, policy, cfg):
     dp = _dp(policy.mesh)
-    kv = policy.kv_cache_spec(cfg.n_kv_heads)     # [B, S, Hkv, hd]
+    kv = policy.kv_cache_spec(cfg.n_kv_heads)     # [B, Hkv, hd, S]
 
     def spec(path, leaf):
         keys = [p.key if hasattr(p, "key") else str(p) for p in path]
         nd = len(leaf.shape)
-        if "kv" in keys:                          # [L, B, S, Hkv, hd]
+        if "kv" in keys:                          # [L, B, Hkv, hd, S]
             return P(None, *kv)
         if "enc_out" in keys:                     # [B, S, D]
             return P(dp, None, None)

@@ -19,6 +19,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.parallel.sharding import shard
 
@@ -224,17 +225,60 @@ def blockwise_attention(
     return out[:, :Sq]
 
 
+def cached_decode_attention(q: Array, k: Array, v: Array, valid_len: Array) -> Array:
+    """One query position against one layer's cache rows, where they lie.
+
+    q [B, 1, H, hd]; k, v [B, Hkv, hd, Smax], the stored cache layout: the
+    scores are a plain matmul over k's rows and the values one against v's
+    minor dim, so the rows are read once, with no pad, reshape or transpose.
+    Rows at or past `valid_len` are masked.  Scores are f32, probabilities
+    travel to the p@v matmul in bf16 and the sum stays f32, as in
+    `blockwise_attention`.
+    """
+    B, _, H, hd = q.shape
+    Hkv, Smax = k.shape[1], k.shape[3]
+    qg = q.reshape(B, Hkv, H // Hkv, hd)
+    sc = jnp.einsum(
+        "bhgd,bhds->bhgs", qg, k, preferred_element_type=jnp.float32,
+    ) * (1.0 / math.sqrt(hd))
+    sc = jnp.where(jnp.arange(Smax) < valid_len, sc, -jnp.inf)
+    pr = jnp.exp(sc - sc.max(-1, keepdims=True))
+    out = jnp.einsum(
+        "bhgs,bhds->bhgd", pr.astype(v.dtype), v, preferred_element_type=jnp.float32,
+    ) / pr.sum(-1)[..., None]
+    return out.reshape(B, 1, H, hd).astype(q.dtype)
+
+
+def cache_write(stack: Array, rows: Array, layer: Array, start: Array) -> Array:
+    """Write one layer's new rows [B, S, Hkv, hd] into the stacked cache
+    [L, B, Hkv, hd, Smax] at positions [start, start + S): one update of
+    the buffer the caller carries, in place when that buffer is donated."""
+    rows = rows.astype(stack.dtype).transpose(0, 2, 3, 1)[None]
+    out = lax.dynamic_update_slice(stack, rows, (layer, 0, 0, 0, start))
+    # Left free, XLA lays the carried stack out for this row write (position
+    # major) and relayouts it around every read and at the program's edges;
+    # row-major is the layout the decode matmuls and the program's arguments
+    # already use, so nothing is copied.
+    return with_layout_constraint(out, Layout(major_to_minor=tuple(range(out.ndim))))
+
+
 def attention(
     params: dict,
     x: Array,                       # [B, S, D]
     positions: Array,               # [B, S] or [S]
     rope_style: str = "full",
     causal: bool = True,
-    cache: Optional[dict] = None,   # {"k": [B,Smax,Hkv,hd], "v":..., "len": []}
+    cache: Optional[dict] = None,   # {"k": [L,B,Hkv,hd,Smax], "v":..., "len": [], "layer": []}
     cross_kv: Optional[tuple] = None,   # precomputed (k, v) for cross-attn
     block_size: int = DEFAULT_BLOCK,
 ) -> tuple[Array, Optional[dict]]:
-    """GQA attention, optionally with a decode cache or cross-attention KV."""
+    """GQA attention, optionally with a decode cache or cross-attention KV.
+
+    With a cache, `cache["k"]`/`["v"]` are the whole layer stack and
+    `cache["layer"]` says which layer this is: the new rows are written
+    into the stack and the layer's rows read back from it, and the updated
+    stack is returned as `{"k", "v"}`.
+    """
     B, S, D = x.shape
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
     if "bq" in params:
@@ -264,26 +308,24 @@ def attention(
                 out = blockwise_attention(q, k, v, causal=causal, block_size=block_size)
             new_cache = None
         else:
-            # decode / chunked prefill: append to cache, attend over it
-            start = cache["len"]
-            ck = lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, start, 0, 0))
-            cv = lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, start, 0, 0))
-            new_cache = {"k": ck, "v": cv, "len": start + S}
-            out = blockwise_attention(
-                q, ck, cv, causal=True, q_offset=start,
-                block_size=block_size, kv_valid_len=start + S,
-            )
+            # decode / chunked prefill: append to the layer's rows, attend over them
+            start, layer = cache["len"], cache["layer"]
+            ck = cache_write(cache["k"], k, layer, start)
+            cv = cache_write(cache["v"], v, layer, start)
+            new_cache = {"k": ck, "v": cv}
+            lk = lax.dynamic_index_in_dim(ck, layer, 0, keepdims=False)
+            lv = lax.dynamic_index_in_dim(cv, layer, 0, keepdims=False)
+            if S == 1:
+                out = cached_decode_attention(q, lk, lv, start + 1)
+            else:
+                out = blockwise_attention(
+                    q, lk.transpose(0, 3, 1, 2), lv.transpose(0, 3, 1, 2),
+                    causal=True, q_offset=start, block_size=block_size,
+                    kv_valid_len=start + S,
+                )
 
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
     return shard(y, "act_btd"), new_cache
-
-
-def make_cache(batch: int, max_seq: int, n_kv: int, head_dim: int, dtype=jnp.bfloat16) -> dict:
-    return {
-        "k": jnp.zeros((batch, max_seq, n_kv, head_dim), dtype),
-        "v": jnp.zeros((batch, max_seq, n_kv, head_dim), dtype),
-        "len": jnp.zeros((), jnp.int32),
-    }
 
 
 # --------------------------------------------------------------------- MLP

@@ -42,16 +42,7 @@ class Model:
         tokens = batch["tokens"]
         if cfg.family == "audio":
             enc_out = T.encode(params, cfg, batch["frames"])
-            cache = {
-                "kv": {
-                    "k": jnp.zeros((cfg.n_layers, tokens.shape[0], tokens.shape[1],
-                                    cfg.n_kv_heads, cfg.hd), jnp.bfloat16),
-                    "v": jnp.zeros((cfg.n_layers, tokens.shape[0], tokens.shape[1],
-                                    cfg.n_kv_heads, cfg.hd), jnp.bfloat16),
-                },
-                "enc_out": enc_out,
-                "len": jnp.zeros((), jnp.int32),
-            }
+            cache = {**T.init_cache(cfg, tokens.shape[0], tokens.shape[1]), "enc_out": enc_out}
             out = T.forward(params, cfg, tokens, cache=cache)
         else:
             prefix = batch.get("patches")

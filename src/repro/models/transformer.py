@@ -10,8 +10,11 @@ independent of depth — essential for compiling 80-layer configs on CPU):
   audio (whisper)   : encoder stack + decoder stack with cross-attention
 
 `forward(..., cache=None)` is training; passing a cache makes the same code
-path do prefill (S tokens into an empty cache) and decode (S=1) — the cache
-is threaded through the scan as per-layer xs/ys.
+path do prefill (S tokens into an empty cache) and decode (S=1).  The K/V
+cache is one stacked buffer per tensor, [L, B, Hkv, hd, Smax], carried
+through the layer scan: each layer writes its new rows into it and reads its
+rows from it (`_attn_block`), so a caller that donates the cache gets it
+updated in place.
 """
 
 from __future__ import annotations
@@ -157,11 +160,12 @@ def init_lm(rng, cfg: ArchConfig) -> dict:
 
 # ============================================================ caches
 def init_cache(cfg: ArchConfig, batch: int, max_seq: int) -> dict:
-    """Decode cache pytree (stacked per scan period)."""
+    """Decode cache pytree (stacked per scan period).  K/V rows are stored
+    [L, B, Hkv, hd, Smax], the layout decode attention reads in place."""
     def kv(n_layers):
         return {
-            "k": jnp.zeros((n_layers, batch, max_seq, cfg.n_kv_heads, cfg.hd), jnp.bfloat16),
-            "v": jnp.zeros((n_layers, batch, max_seq, cfg.n_kv_heads, cfg.hd), jnp.bfloat16),
+            "k": jnp.zeros((n_layers, batch, cfg.n_kv_heads, cfg.hd, max_seq), jnp.bfloat16),
+            "v": jnp.zeros((n_layers, batch, cfg.n_kv_heads, cfg.hd, max_seq), jnp.bfloat16),
         }
 
     if cfg.family in ("dense", "vlm", "moe"):
@@ -194,11 +198,14 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int) -> dict:
 
 
 # ============================================================ forward
-def _attn_block(cfg, blk, h, positions, cache_kv, cache_len, cross_kv=None):
-    """One attention (or cross-attention) residual branch."""
-    cache = None
-    if cache_kv is not None:
-        cache = {"k": cache_kv["k"], "v": cache_kv["v"], "len": cache_len}
+def _attn_block(cfg, blk, h, positions, kv, layer, cache_len, cross_kv=None):
+    """One attention (or cross-attention) residual branch.
+
+    `kv` is the stacked cache {"k", "v"} that the layer scan carries (None
+    without a cache): the branch writes layer `layer`'s new rows into it and
+    attends over that layer's rows.  Every family's cached scan body goes
+    through here.  Returns (h, kv)."""
+    cache = None if kv is None else {**kv, "len": cache_len, "layer": layer}
     y, new_cache = L.attention(
         blk["attn"], L.rmsnorm(h, blk["ln1"]["scale"], cfg.norm_eps),
         positions, cfg.rope_style, causal=True, cache=cache,
@@ -210,8 +217,7 @@ def _attn_block(cfg, blk, h, positions, cache_kv, cache_len, cross_kv=None):
             positions, "none", causal=False, cross_kv=cross_kv,
         )
         h = h + yx
-    kv_out = {"k": new_cache["k"], "v": new_cache["v"]} if new_cache else None
-    return h, kv_out
+    return h, new_cache
 
 
 def _ffn_block(cfg, blk, h):
@@ -242,34 +248,35 @@ def forward(
     zl = jnp.zeros(())
 
     if cfg.family in ("dense", "vlm", "moe"):
-        kv_in = cache["kv"] if cache is not None else None
+        kv = cache["kv"] if cache is not None else None
 
         def body(carry, xs):
-            h, aux, zl = carry
-            blk, kv = xs
-            h, kv_out = _attn_block(cfg, blk, h, positions, kv, start)
+            h, aux, zl, kv = carry
+            blk, i = xs
+            h, kv = _attn_block(cfg, blk, h, positions, kv, i, start)
             h, a, z = _ffn_block(cfg, blk, h)
-            return (h, aux + a, zl + z), kv_out
+            return (h, aux + a, zl + z, kv), None
 
-        (h, aux, zl), kv_out = lax.scan(_maybe_remat(body), (h, aux, zl), (params["blocks"], kv_in))
-        new_cache = None if cache is None else {"kv": kv_out, "len": start + S}
+        (h, aux, zl, kv), _ = lax.scan(
+            _maybe_remat(body), (h, aux, zl, kv), (params["blocks"], jnp.arange(cfg.n_layers))
+        )
+        new_cache = None if cache is None else {"kv": kv, "len": start + S}
 
     elif cfg.family == "hybrid":
         period = cfg.attn_period
         attn_pos = period // 2
-        kv_in = cache["kv"] if cache is not None else None
         mamba_in = cache["mamba"] if cache is not None else None
         decode = cache is not None and S == 1
 
         def body(carry, xs):
-            h, aux, zl = carry
-            per, kv, mst = xs
+            h, aux, zl, kv = carry
+            per, i, mst = xs
             m_i = 0
             ffn_i = {"moe": 0, "mlp": 0}
-            kv_out, mst_out = kv, mst
+            mst_out = mst
             for j in range(period):
                 if j == attn_pos:
-                    h, kv_out = _attn_block(cfg, per["attn"], h, positions, kv, start)
+                    h, kv = _attn_block(cfg, per["attn"], h, positions, kv, i, start)
                 else:
                     mp = jax.tree.map(lambda x, i=m_i: x[i], per["mamba"])
                     xn = L.rmsnorm(h, mp["ln1"]["scale"], cfg.norm_eps)
@@ -289,7 +296,7 @@ def forward(
                 h, a, z = _ffn_block(cfg, fp, h)
                 ffn_i[key] += 1
                 aux, zl = aux + a, zl + z
-            return (h, aux, zl), (kv_out, mst_out)
+            return (h, aux, zl, kv), mst_out
 
         n_p = cfg.n_layers // period
         if cache is None:
@@ -300,7 +307,7 @@ def forward(
                 ffn_i = {"moe": 0, "mlp": 0}
                 for j in range(period):
                     if j == attn_pos:
-                        h, _ = _attn_block(cfg, per["attn"], h, positions, None, start)
+                        h, _ = _attn_block(cfg, per["attn"], h, positions, None, None, start)
                     else:
                         mp = jax.tree.map(lambda x, i=m_i: x[i], per["mamba"])
                         xn = L.rmsnorm(h, mp["ln1"]["scale"], cfg.norm_eps)
@@ -317,10 +324,10 @@ def forward(
             (h, aux, zl), _ = lax.scan(_maybe_remat(body_nocache), (h, aux, zl), params["periods"])
             new_cache = None
         else:
-            (h, aux, zl), (kv_out, mst_out) = lax.scan(
-                body, (h, aux, zl), (params["periods"], kv_in, mamba_in)
+            (h, aux, zl, kv), mst_out = lax.scan(
+                body, (h, aux, zl, cache["kv"]), (params["periods"], jnp.arange(n_p), mamba_in)
             )
-            new_cache = {"kv": kv_out, "mamba": mst_out, "len": start + S}
+            new_cache = {"kv": kv, "mamba": mst_out, "len": start + S}
 
     elif cfg.family == "ssm":
         period = cfg.slstm_period
@@ -381,17 +388,19 @@ def forward(
         h = h + L.sinusoidal_pos(positions[0], cfg.d_model).astype(h.dtype)[None]
 
         def body(carry, xs):
-            h, aux, zl = carry
-            blk, kv = xs
+            h, aux, zl, kv = carry
+            blk, i = xs
             # cross KV computed from encoder output per layer
             xk = jnp.einsum("bsd,dhk->bshk", enc_out, blk["xattn"]["wk"])
             xv = jnp.einsum("bsd,dhk->bshk", enc_out, blk["xattn"]["wv"])
-            h, kv_out = _attn_block(cfg, blk, h, positions, kv, start, cross_kv=(xk, xv))
+            h, kv = _attn_block(cfg, blk, h, positions, kv, i, start, cross_kv=(xk, xv))
             h, a, z = _ffn_block(cfg, blk, h)
-            return (h, aux + a, zl + z), kv_out
+            return (h, aux + a, zl + z, kv), None
 
-        (h, aux, zl), kv_out = lax.scan(_maybe_remat(body), (h, aux, zl), (params["blocks"], cache["kv"]))
-        new_cache = {"kv": kv_out, "enc_out": enc_out, "len": start + S}
+        (h, aux, zl, kv), _ = lax.scan(
+            _maybe_remat(body), (h, aux, zl, cache["kv"]), (params["blocks"], jnp.arange(cfg.n_layers))
+        )
+        new_cache = {"kv": kv, "enc_out": enc_out, "len": start + S}
     else:
         raise ValueError(cfg.family)
 

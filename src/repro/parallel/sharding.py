@@ -62,12 +62,12 @@ class ShardingPolicy:
         return table[name]
 
     def kv_cache_spec(self, n_kv_heads: int) -> P:
-        """[B, S, Hkv, hd] cache layout."""
+        """[B, Hkv, hd, S] cache layout (`models.transformer.init_cache`)."""
         dp = _dp(self.mesh)
         tp = self.mesh.shape.get("model", 1)
         if self.kv_seq_shard or n_kv_heads < tp:
-            return P(dp, "model", None, None)  # sequence parallelism
-        return P(dp, None, "model", None)      # head parallelism
+            return P(dp, None, None, "model")  # sequence parallelism
+        return P(dp, "model", None, None)      # head parallelism
 
     def ssm_state_spec(self) -> P:
         """[B, d_inner, N] SSM state: channels over model."""

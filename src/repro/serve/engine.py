@@ -25,7 +25,10 @@ touches, not by who calls it:
 
 Device-side the engine runs two jitted programs: `prefill` (one sequence at
 a time into its cache lane) and `decode_step` (all active lanes, one token).
-Slots are fixed (static shapes); finished lanes are recycled.
+Slots are fixed (static shapes); finished lanes are recycled.  Both programs
+take the cache donated and update it in place, so the engine owns the only
+live cache: `self.cache` is replaced by each dispatch's result, and no caller
+may keep its arrays across `schedule()` (DESIGN.md §9.4).
 
 `schedule()` is the unified scheduler tick — admit, decode, recycle — and
 `run_until_drained` loops it, raising `DrainError` (with the undrained
@@ -154,8 +157,9 @@ class ServeEngine:
         # host stand-in for window-region disjointness (see module docstring)
         self._cache_mu = threading.Lock()
         self.recycled_total = 0
-        self._decode = jax.jit(model.decode_step)
-        self._prefill = jax.jit(self._prefill_impl, static_argnames=("plen",))
+        self._decode = jax.jit(model.decode_step, donate_argnames="cache")
+        self._prefill = jax.jit(self._prefill_impl, static_argnames=("plen",),
+                                donate_argnames=("cache",))
         registry = obs_metrics.REGISTRY if metrics is None else metrics
         self._decode_counters = _ProgramCounters(registry, "decode", self._decode)
         self._prefill_counters = _ProgramCounters(registry, "prefill", self._prefill)
@@ -165,7 +169,8 @@ class ServeEngine:
 
     # --------------------------------------------------------- plumbing
     def _prefill_impl(self, params, cache, tokens, slot, plen):
-        """Prefill one slot's lane: write K/V rows for [0, plen)."""
+        """Prefill one slot's lane: write K/V rows for [0, plen).  `cache`
+        is donated, so the lane write updates 1/n_slots of it in place."""
         # run the model on this single sequence with a fresh single-lane cache
         lane_cache = self.model.init_cache(1, self.max_seq)
         logits, lane_cache = self.model.prefill(params, tokens[None, :plen], lane_cache, None)
@@ -175,7 +180,6 @@ class ServeEngine:
             b_axis = _batch_axis(full.shape, lane.shape)
             if b_axis is None:
                 return full
-            idx = [slice(None)] * full.ndim
             return jax.lax.dynamic_update_index_in_dim(full, lane[_take0(b_axis, lane.ndim)], slot, b_axis)
 
         new_cache = jax.tree.map(put, cache, lane_cache)

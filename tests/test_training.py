@@ -137,6 +137,46 @@ class TestServeEngine:
         assert req.output == want, (req.output, want)
         assert eng.queue.empty() and all(eng.slot_free)
 
+    def test_engine_donates_its_cache_and_reuses_lanes(self):
+        """Both compiled programs alias the donated K/V cache, and two waves
+        through one engine (each lane prefilled, recycled and written again)
+        serve the tokens of a hand-rolled prefill/decode loop."""
+        from repro.serve.engine import Request, ServeEngine
+
+        cfg = get_config("smollm-360m", smoke=True)
+        model = build_model(cfg)
+        params = model.init(RNG)
+        max_seq, n_new = 32, 5
+        eng = ServeEngine(model, params, n_slots=2, max_seq=max_seq)
+        kv_bytes = sum(a.nbytes for a in jax.tree.leaves(eng.cache["kv"]))
+        programs = [
+            eng._decode.lower(params, jnp.zeros((2,), jnp.int32), eng.cache),
+            eng._prefill.lower(params, eng.cache, jnp.zeros((max_seq,), jnp.int32), 0, plen=4),
+        ]
+        for lowered in programs:
+            assert lowered.compile().memory_analysis().alias_size_in_bytes >= kv_bytes
+
+        def direct(prompt):
+            cache = model.init_cache(1, max_seq)
+            logits, cache = model.prefill(params, jnp.asarray([prompt], jnp.int32), cache, None)
+            out = []
+            for _ in range(n_new):
+                tok = jnp.argmax(logits, -1)
+                out.append(int(tok[0]))
+                logits, cache = model.decode_step(params, tok, cache)
+            return out
+
+        # one prompt length per wave: the cache holds one length for all lanes
+        waves = [[[3, 1, 4, 1], [2, 7, 1, 8]], [[5, 9, 2, 6, 5, 3], [5, 8, 9, 7, 9, 3]]]
+        for w, prompts in enumerate(waves):
+            reqs = [Request(rid=10 * w + i, prompt=p, max_new=n_new) for i, p in enumerate(prompts)]
+            for r in reqs:
+                eng.submit(r)
+            eng.run_until_drained()
+            for r in reqs:
+                assert r.output == direct(r.prompt), (r.rid, r.output)
+        assert eng.recycled_total == 4
+
     def test_engine_interleaves_requests(self):
         from repro.serve.engine import Request, ServeEngine
 
