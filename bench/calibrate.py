@@ -33,7 +33,8 @@ def readings(session, seed: int, seconds: float, control: bool, reference) -> di
     control's worst gap on the same prompts and tokens."""
     import numpy as np
 
-    from bench import check, weights
+    from bench import check
+    from bench.family import root_key
 
     session.load(seed)
     window = session.window(seed, seconds)
@@ -41,16 +42,17 @@ def readings(session, seed: int, seconds: float, control: bool, reference) -> di
     done = [r for r in window.requests if len(r.output) == r.max_new]
     reqs = check.sample(done, int(session.traffic["check_requests"]), seed)
     tokens, idx, served, valid = check.batch(reqs)
-    root = weights.root_key(seed)
-    ref = np.asarray(reference.logits_at(session.dims, root, tokens, idx))
+    root = root_key(seed)
+    ref = np.asarray(reference.logits_at(session.family, session.dims, root,
+                                         tokens, idx))
     out = {"seed": seed, "requests": len(window.requests),
            "failed": len(window.requests) - len(done),
            "tokens_compared": int(valid.sum()),
            "program_max_gap": float(check.gaps(ref, served)[valid].max())}
     if control:
         try:
-            ctl = np.asarray(reference.logits_at(session.dims, root, tokens, idx,
-                                                 quant="fp8"))
+            ctl = np.asarray(reference.logits_at(session.family, session.dims, root,
+                                                 tokens, idx, quant="fp8"))
             out["control_max_gap"] = float(
                 check.gaps(ref, ctl.argmax(-1))[valid].max())
         except Exception as e:  # a control that crashes has failed

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench import weights
+from bench.family import root_key
 
 
 def sample(requests: list, k: int, seed: int) -> list:
@@ -61,7 +61,9 @@ def gaps(logits, served: np.ndarray) -> np.ndarray:
 
 
 def check_window(requests: list, config: dict, traffic: dict, seed: int,
-                 reference, log=print) -> dict:
+                 reference, family, log=print) -> dict:
+    """The verdict on a window's requests: `reference` is the configuration's
+    plain reference, which makes the seed's weights with `family`'s makers."""
     failed = sum(1 for r in requests if len(r.output) != r.max_new)
     reqs = sample([r for r in requests if len(r.output) == r.max_new],
                   int(traffic["check_requests"]), seed)
@@ -69,7 +71,7 @@ def check_window(requests: list, config: dict, traffic: dict, seed: int,
     worst = None
     if reqs:
         tokens, idx, served, valid = batch(reqs)
-        logits = reference.logits_at(config["dims"], weights.root_key(seed),
+        logits = reference.logits_at(family, config["dims"], root_key(seed),
                                      tokens, idx)
         g = gaps(logits, served)
         worst = float(g[valid].max())

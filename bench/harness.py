@@ -16,11 +16,13 @@ import shutil
 import sys
 import tempfile
 import time
+import types
 from typing import Optional
 
 import jax
 
-from bench import check, client, spec, trace_reduce, traffic as traffic_mod, weights
+from bench import check, client, spec, trace_reduce, traffic as traffic_mod
+from bench.family import root_key
 from bench.peaks import peaks_for
 
 
@@ -30,7 +32,10 @@ class NoChip(RuntimeError):
 
 @dataclasses.dataclass
 class Run:
-    """What the metric readers read."""
+    """What the metric readers read.  `family` is the configuration's
+    family module, whose `prefill_work` and `decode_work` the roofline and
+    MFU readers count with."""
+    family: types.ModuleType
     dims: dict
     n_slots: int
     chips: int
@@ -93,12 +98,13 @@ class Session:
         eng = self.config["engine"]
         self.n_slots, self.max_seq = eng["n_slots"], eng["max_seq"]
         arch = get_config(self.config["arch"], smoke=self.config.get("smoke", False))
-        spec.check_widths(self.config, arch)
+        self.family = spec.family_module(self.config)
+        self.family.check_widths(self.config, arch)
         self.model = build_model(arch)
-        weights.check_layout(self.model.init_shapes(), self.dims)
+        self.family.check_layout(self.model.init_shapes(), self.dims)
         traffic_mod.validate(self.traffic, self.max_seq)
         self.Request = Request
-        self._make = jax.jit(lambda r: weights.program_params(r, self.dims))
+        self._make = jax.jit(lambda r: self.family.program_params(r, self.dims))
         self.engine = ServeEngine(self.model, None, n_slots=self.n_slots,
                                   max_seq=self.max_seq)
 
@@ -106,7 +112,7 @@ class Session:
         """Make the seed's weights on the device, in one jitted call."""
         self.engine.params = None
         gc.collect()
-        self.engine.params = self._make(weights.root_key(seed))
+        self.engine.params = self._make(root_key(seed))
         jax.block_until_ready(self.engine.params)
 
     def release(self) -> None:
@@ -193,7 +199,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     stamp("devices found")
     with CountCompiles() as setup_compiles:
         s = Session(cell)
-        stamp("engine built")
+        stamp(f"engine built, {s.family.n_params(s.dims)} parameters")
         s.load(seed)
         stamp("weights made")
         s.warm_up()
@@ -215,7 +221,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     for w in client.wave_summary(window):
         log(" ".join(f"{k} {v}" for k, v in w.items()))
 
-    run = Run(s.dims, s.n_slots, cell.chips, peaks, setup_s, window)
+    run = Run(s.family, s.dims, s.n_slots, cell.chips, peaks, setup_s, window)
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices), "memory_peak_bytes": mem_peak}
     extra = {}
@@ -235,7 +241,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     # ----------------------------------------------------------- check
     s.release()
     verdict = check.check_window(window.requests, s.config, s.traffic, seed,
-                                 spec.reference_module(s.config), log=log)
+                                 spec.reference_module(s.config), s.family,
+                                 log=log)
     for name, c in verdict["checks"].items():
         log(f"check {name} {c['value']!r} limit {c['limit']!r}")
     return {

@@ -14,12 +14,13 @@ random weights this is a fixed permutation of the `wq`/`wk` columns and
 changes no cost.
 
 It imports nothing of the program under test and never reads its weights:
-it makes the same bf16 values from the seed with `bench.weights`, one layer
-at a time, and upcasts them, so it never holds a float32 copy of the whole
-model.  `quant="fp8"` computes every linear layer with float8 (e4m3)
-operands instead, scaled per row of activations and per output channel of
-weights: the precision below the bf16 that the configurations state, which
-is the control that the comparison must fail.
+it makes the same bf16 values from the seed with the configuration's family
+module (`make_globals`, `make_layer`), one layer at a time, and upcasts
+them, so it never holds a float32 copy of the whole model.  `quant="fp8"`
+computes every linear layer with float8 (e4m3) operands instead, scaled per
+row of activations and per output channel of weights: the precision below
+the bf16 that the configurations state, which is the control that the
+comparison must fail.
 """
 
 from __future__ import annotations
@@ -31,8 +32,6 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from bench import weights as W
 
 F8_MAX = 448.0  # largest finite float8_e4m3fn
 
@@ -91,7 +90,7 @@ def _block(dims, w, h, quant):
 
 
 @functools.lru_cache(maxsize=None)
-def _programs(dims_json: str, quant):
+def _programs(family, dims_json: str, quant):
     dims = json.loads(dims_json)
 
     def f32(tree):
@@ -99,16 +98,16 @@ def _programs(dims_json: str, quant):
 
     @jax.jit
     def embed(root, tokens):
-        return f32(W.make_globals(root, dims))["embed"][tokens]
+        return f32(family.make_globals(root, dims))["embed"][tokens]
 
     @jax.jit
     def layer(root, i, h):
-        w = f32(W.make_layer(root, dims, i))
+        w = f32(family.make_layer(root, dims, i))
         return jax.lax.map(lambda hs: _block(dims, w, hs, quant), h)
 
     @jax.jit
     def head(root, h, idx):
-        g = f32(W.make_globals(root, dims))
+        g = f32(family.make_globals(root, dims))
         x = jnp.take_along_axis(h, idx[..., None], axis=1)
         x = _fq(_rmsnorm(x, g["final_norm"], dims["norm_eps"]), (-1,), quant)
         wh = g["lm_head"] if "lm_head" in g else g["embed"].T
@@ -117,12 +116,13 @@ def _programs(dims_json: str, quant):
     return embed, layer, head
 
 
-def logits_at(dims: dict, root, tokens: np.ndarray, idx: np.ndarray,
+def logits_at(family, dims: dict, root, tokens: np.ndarray, idx: np.ndarray,
               quant=None) -> jax.Array:
     """Float32 logits [B, n, vocab] at positions `idx` [B, n] of the
-    sequences `tokens` [B, S].  Padding after a sequence's end changes none
-    of its positions, since attention is causal."""
-    embed, layer, head = _programs(json.dumps(dims, sort_keys=True), quant)
+    sequences `tokens` [B, S], with the weights that `family`'s makers give
+    `root`.  Padding after a sequence's end changes none of its positions,
+    since attention is causal."""
+    embed, layer, head = _programs(family, json.dumps(dims, sort_keys=True), quant)
     with jax.default_matmul_precision("highest"):
         h = embed(root, jnp.asarray(tokens, jnp.int32))
         for i in range(dims["n_layers"]):

@@ -3,18 +3,21 @@
 - `BENCHMARK.json` at the checkout's root lists configurations, cells and
   metrics;
 - a configuration is the JSON file its entry names, under `bench/configs/`;
+  its `family` names `bench/family/<family>.py`, everything the benchmark
+  knows of that family's shapes (`bench/family/__init__.py` lists it), and
   its `reference` names `bench/reference/<reference>.py`;
 - a traffic mix is `bench/traffic/<traffic>.json`;
 - a metric is `bench/metrics/<name>.py`, whose `read(run)` returns the value
   or None when the run holds nothing to read.
 
-Adding a configuration, a mix or a metric is adding files and entries; no
-code here names one.
+Adding a configuration, of a family already here or of a new one, a mix or
+a metric is adding files and entries; no code here names one.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import importlib.util
 import json
 from pathlib import Path
@@ -78,24 +81,8 @@ def reference_module(config: dict, bench: Path = BENCH):
                         f"bench_reference_{config['reference']}")
 
 
-def check_widths(config: dict, arch_cfg) -> None:
-    """Refuse a configuration whose stated widths the program's own
-    `repro.configs` entry does not share."""
-    d = config["dims"]
-    have = {
-        "n_layers": arch_cfg.n_layers, "d_model": arch_cfg.d_model,
-        "n_heads": arch_cfg.n_heads, "n_kv_heads": arch_cfg.n_kv_heads,
-        "head_dim": arch_cfg.hd, "d_ff": arch_cfg.d_ff,
-        "vocab": arch_cfg.vocab_size,
-        "tie_embeddings": arch_cfg.tie_embeddings,
-        "qkv_bias": arch_cfg.qkv_bias,
-        "rotary_dims": arch_cfg.hd // 2 if arch_cfg.rope_style == "2d" else arch_cfg.hd,
-        "norm_eps": arch_cfg.norm_eps,
-        "family": arch_cfg.family,
-    }
-    want = {k: d[k] for k in have if k in d}
-    want["family"] = config["family"]
-    bad = {k: (want[k], have[k]) for k in have if want.get(k) != have[k]}
-    if bad:
-        raise ValueError(f"configuration {config['name']!r} disagrees with "
-                         f"repro.configs {config['arch']!r} (stated, program): {bad}")
+def family_module(config: dict):
+    """The model family that the configuration names, imported as
+    `bench.family.<family>`: one module for the process, however often the
+    harness, the check and the readers ask for it."""
+    return importlib.import_module(f"bench.family.{config['family']}")

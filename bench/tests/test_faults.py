@@ -4,6 +4,7 @@ is planted under the engine, the rest of a run goes on as usual, and
 between chips are faults of training and of cells on several chips; a
 one-chip serving cell has neither.)"""
 
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -23,10 +24,13 @@ def _patch_decode(monkeypatch, wrap):
 
 
 def state_unchanged(decode):
-    """The decode step returns the cache it was given: no K/V row lands."""
+    """The decode step returns the cache as it was given: no K/V row lands.
+    The engine donates the cache to its decode, so the fault keeps a copy
+    taken before the call and returns that."""
     def f(params, tokens, cache):
+        kept = jax.tree.map(jnp.copy, cache)
         logits, _ = decode(params, tokens, cache)
-        return logits, cache
+        return logits, kept
     return f
 
 

@@ -34,7 +34,7 @@ def test_config_widths_agree_with_the_program(name):
 
     entry = {c["name"]: c for c in BENCH_JSON["configs"]}[name]
     config = spec.load_json(spec.CHECKOUT / entry["file"])
-    spec.check_widths(config, get_config(config["arch"]))
+    spec.family_module(config).check_widths(config, get_config(config["arch"]))
     assert sorted(config["reduced"]) == sorted(entry["reduced"])
 
 
@@ -44,7 +44,7 @@ def test_a_wrong_width_is_refused():
     config = spec.load_cell(CELLS[0]).config
     bad = dict(config, dims=dict(config["dims"], d_ff=config["dims"]["d_ff"] + 1))
     with pytest.raises(ValueError, match="d_ff"):
-        spec.check_widths(bad, get_config(config["arch"]))
+        spec.family_module(bad).check_widths(bad, get_config(config["arch"]))
 
 
 def test_new_files_are_found_by_name(tmp_path):

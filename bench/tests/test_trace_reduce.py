@@ -50,20 +50,18 @@ def test_breakdown(summary):
 
 
 def test_per_layer_readers_read_the_trace(summary):
-    """Every per-layer metric of a decode cell reads the recorded trace
-    beside the client's record: a share lies in (0, 100]."""
-    from bench import client, harness, spec
-    from bench.peaks import PEAKS
-    from helpers import SMOKE_CONFIGS, smoke_cell
+    """Every per-layer metric of a decode cell that reads the device trace
+    reads the recorded trace beside the client's record: a share lies in
+    (0, 100].  (The counter readers read the process's registry, which
+    holds nothing unless an engine has run in this process.)"""
+    from bench import spec
+    from helpers import SMOKE_CONFIGS, recorded_run, smoke_cell
 
-    rec = json.loads(RECORD.read_text())
     cell = smoke_cell(SMOKE_CONFIGS[0])
-    ticks = [client.Tick(p, a, True) for p, a in zip(rec["positions"], rec["active"])]
-    prefills = [client.Prefill(p, True) for p in rec["plens"]]
-    window = client.Window([], ticks, prefills, 0.0, 1.0, 1)
-    run = harness.Run(cell.config["dims"], cell.config["engine"]["n_slots"], 1,
-                      PEAKS["TPU v5 lite"], 1.0, window, summary)
-    for m in cell.per_layer:
+    run = recorded_run(cell.config, summary)
+    traced = [m for m in cell.per_layer if m["source"] == "device_trace"]
+    assert traced
+    for m in traced:
         value = spec.metric_reader(m["name"])(run)
         assert value is not None and value > 0, m["name"]
         if m["unit"] == "%":
