@@ -13,7 +13,7 @@ _MODULES = {
     "llava-next-mistral-7b": "llava_next_mistral_7b",
     "whisper-small": "whisper_small",
     "qwen3-moe-30b-a3b": "qwen3_moe_30b_a3b",
-    "moonshot-v1-16b-a3b": "moonshot_v1_16b_a3b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 ARCH_IDS = list(_MODULES)

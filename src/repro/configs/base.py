@@ -35,6 +35,17 @@ class ArchConfig:
     moe_d_ff: int = 0                # per-expert hidden dim
     moe_every: int = 1               # MoE FFN on every k-th layer (others dense)
     moe_shared_ff: int = 0           # shared-expert hidden dim (0 = none)
+    moe_router: str = "softmax"      # softmax | sigmoid (noaux_tc: top-k on score + bias)
+    moe_route_scale: float = 1.0     # routed_scaling_factor on the top-k weights
+    moe_held: int = 0                # init makes experts 0..moe_held-1 only, a serving share (0: all)
+    first_k_dense: int = 0           # leading layers with a dense MLP of width d_ff
+
+    # --- latent attention (MLA); kv_lora_rank 0 = none ---
+    kv_lora_rank: int = 0            # latent width, cached per position
+    qk_nope_head_dim: int = 0        # per-head query/key dims without rotary
+    qk_rope_head_dim: int = 0        # rotary dims; one rotary key shared by all heads
+    v_head_dim: int = 0
+    rope_theta: float = 10_000.0
 
     # --- hybrid / SSM ---
     ssm_type: str = "none"           # none | mamba | xlstm
@@ -102,6 +113,13 @@ def shape_applicable(cfg: ArchConfig, shape: ShapeConfig) -> tuple[bool, str]:
 
 # ----------------------------------------------------------- param counting
 def _attn_params(cfg: ArchConfig) -> int:
+    if cfg.kv_lora_rank:  # MLA: q, latent + rotary key, latent norm, W_UK/W_UV, out
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        return (cfg.d_model * cfg.n_heads * qk
+                + cfg.d_model * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+                + cfg.kv_lora_rank
+                + cfg.kv_lora_rank * cfg.n_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+                + cfg.n_heads * cfg.v_head_dim * cfg.d_model)
     hd = cfg.hd
     q = cfg.d_model * cfg.n_heads * hd
     kv = 2 * cfg.d_model * cfg.n_kv_heads * hd
@@ -164,11 +182,14 @@ def _layer_params(cfg: ArchConfig, layer_idx: int, active_only: bool) -> int:
     elif cfg.family == "ssm":
         total += _xlstm_params(cfg) if cfg.ssm_type == "xlstm" else _mamba_params(cfg)
     # channel mixer
-    is_moe_layer = cfg.moe_experts > 0 and (layer_idx % cfg.moe_every == cfg.moe_every - 1)
+    is_moe_layer = (cfg.moe_experts > 0 and layer_idx >= cfg.first_k_dense
+                    and layer_idx % cfg.moe_every == cfg.moe_every - 1)
     if is_moe_layer:
         e = cfg.moe_top_k if active_only else cfg.moe_experts
         total += e * _mlp_params(cfg, cfg.moe_d_ff)
         total += cfg.d_model * cfg.moe_experts  # router
+        if cfg.moe_router == "sigmoid":
+            total += cfg.moe_experts  # e_score_correction_bias
         if cfg.moe_shared_ff:
             total += _mlp_params(cfg, cfg.moe_shared_ff)
     elif cfg.d_ff > 0:

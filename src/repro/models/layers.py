@@ -140,6 +140,7 @@ def blockwise_attention(
     block_size: int = DEFAULT_BLOCK,
     kv_valid_len: Optional[Array] = None,  # mask out cache slots >= this
     block_q: Optional[int] = None,
+    scale: Optional[float] = None,         # default 1/sqrt(q's width)
 ) -> Array:
     """Flash-structured attention on the XLA path: outer scan over Q chunks,
     inner online-softmax scan over KV blocks.
@@ -152,11 +153,12 @@ def blockwise_attention(
     bf16 (standard flash practice); accumulation stays f32.
 
     GQA: H must be a multiple of Hkv; kv heads are broadcast per group.
+    `v` may be narrower or wider than `q` and `k` (latent attention).
     """
     B, Sq, H, hd = q.shape
-    Sk, Hkv = k.shape[1], k.shape[2]
+    Sk, Hkv, hv = k.shape[1], k.shape[2], v.shape[-1]
     g = H // Hkv
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     bq = min(block_q or block_size, Sq)
     bk = min(block_size, Sk)
 
@@ -171,7 +173,7 @@ def blockwise_attention(
     # [nq, B, Hkv, g, bq, hd] / [nk, B, Hkv, bk, hd]
     qb = qp.reshape(B, nq, bq, Hkv, g, hd).transpose(1, 0, 3, 4, 2, 5)
     kb = kp.reshape(B, nk, bk, Hkv, hd).transpose(1, 0, 3, 2, 4)
-    vb = vp.reshape(B, nk, bk, Hkv, hd).transpose(1, 0, 3, 2, 4)
+    vb = vp.reshape(B, nk, bk, Hkv, hv).transpose(1, 0, 3, 2, 4)
 
     def q_chunk(carry, xs):
         iq, qblk = xs                                    # qblk [B,Hkv,g,bq,hd]
@@ -206,7 +208,7 @@ def blockwise_attention(
 
         m0 = jnp.full((B, Hkv, g, bq), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((B, Hkv, g, bq), jnp.float32)
-        a0 = jnp.zeros((B, Hkv, g, bq, hd), jnp.float32)
+        a0 = jnp.zeros((B, Hkv, g, bq, hv), jnp.float32)
         # checkpoint per KV block too: the backward otherwise stacks every
         # block's score matrix (a full S x S residual per layer)
         (m, l, acc), _ = lax.scan(
@@ -220,33 +222,36 @@ def blockwise_attention(
     _, outs = lax.scan(
         jax.checkpoint(q_chunk, prevent_cse=False), 0, (jnp.arange(nq), qb)
     )
-    # [nq, B, Hkv, g, bq, hd] -> [B, Sq, H, hd]
-    out = outs.transpose(1, 0, 4, 2, 3, 5).reshape(B, nq * bq, H, hd)
+    # [nq, B, Hkv, g, bq, hv] -> [B, Sq, H, hv]
+    out = outs.transpose(1, 0, 4, 2, 3, 5).reshape(B, nq * bq, H, hv)
     return out[:, :Sq]
 
 
-def cached_decode_attention(q: Array, k: Array, v: Array, valid_len: Array) -> Array:
+def cached_decode_attention(q: Array, k: Array, v: Array, valid_len: Array,
+                            scale: Optional[float] = None) -> Array:
     """One query position against one layer's cache rows, where they lie.
 
-    q [B, 1, H, hd]; k, v [B, Hkv, hd, Smax], the stored cache layout: the
-    scores are a plain matmul over k's rows and the values one against v's
-    minor dim, so the rows are read once, with no pad, reshape or transpose.
-    Rows at or past `valid_len` are masked.  Scores are f32, probabilities
-    travel to the p@v matmul in bf16 and the sum stays f32, as in
-    `blockwise_attention`.
+    q [B, 1, H, hd]; k [B, Hkv, hd, Smax], v [B, Hkv, hv, Smax], the stored
+    cache layout: the scores are a plain matmul over k's rows and the values
+    one against v's minor dim, so the rows are read once, with no pad,
+    reshape or transpose.  `v` may be `k` itself (the absorbed latent
+    attention reads the latent rows as both).  Rows at or past
+    `valid_len` are masked.  Scores are f32 (scaled by `scale`, default
+    1/sqrt(hd)), probabilities travel to the p@v matmul in bf16 and the sum
+    stays f32, as in `blockwise_attention`.
     """
     B, _, H, hd = q.shape
-    Hkv, Smax = k.shape[1], k.shape[3]
+    Hkv, Smax, hv = k.shape[1], k.shape[3], v.shape[2]
     qg = q.reshape(B, Hkv, H // Hkv, hd)
     sc = jnp.einsum(
         "bhgd,bhds->bhgs", qg, k, preferred_element_type=jnp.float32,
-    ) * (1.0 / math.sqrt(hd))
+    ) * (1.0 / math.sqrt(hd) if scale is None else scale)
     sc = jnp.where(jnp.arange(Smax) < valid_len, sc, -jnp.inf)
     pr = jnp.exp(sc - sc.max(-1, keepdims=True))
     out = jnp.einsum(
         "bhgs,bhds->bhgd", pr.astype(v.dtype), v, preferred_element_type=jnp.float32,
     ) / pr.sum(-1)[..., None]
-    return out.reshape(B, 1, H, hd).astype(q.dtype)
+    return out.reshape(B, 1, H, hv).astype(q.dtype)
 
 
 def cache_write(stack: Array, rows: Array, layer: Array, start: Array) -> Array:
@@ -271,6 +276,7 @@ def attention(
     cache: Optional[dict] = None,   # {"k": [L,B,Hkv,hd,Smax], "v":..., "len": [], "layer": []}
     cross_kv: Optional[tuple] = None,   # precomputed (k, v) for cross-attn
     block_size: int = DEFAULT_BLOCK,
+    theta: float = 10_000.0,
 ) -> tuple[Array, Optional[dict]]:
     """GQA attention, optionally with a decode cache or cross-attention KV.
 
@@ -294,8 +300,8 @@ def attention(
         v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
         if "bk" in params:
             k, v = k + params["bk"], v + params["bv"]
-        q = apply_rope(q, positions, rope_style)
-        k = apply_rope(k, positions, rope_style)
+        q = apply_rope(q, positions, rope_style, theta)
+        k = apply_rope(k, positions, rope_style, theta)
         if cache is None:
             if _ATTN_BACKEND[0] == "pallas":
                 from repro.kernels.flash_attention.ops import flash_attention
@@ -325,6 +331,96 @@ def attention(
                 )
 
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
+    return shard(y, "act_btd"), new_cache
+
+
+# ------------------------------------------------ latent attention (MLA)
+# DeepSeek-V3 builds its latent RMSNorm without an eps, so the norm's own
+# default holds there, not the model's rms_norm_eps.
+MLA_NORM_EPS = 1e-6
+
+
+def init_mla(rng, d_model: int, n_heads: int, kv_lora: int, nope: int, rope: int,
+             v_dim: int, dtype=jnp.bfloat16) -> dict:
+    """Multi-head latent attention with one q projection (no q latent)."""
+    ks = jax.random.split(rng, 5)
+    s, s_l = 1.0 / math.sqrt(d_model), 1.0 / math.sqrt(kv_lora)
+
+    def normal(k, shape, std):
+        return (jax.random.normal(k, shape) * std).astype(dtype)
+
+    return {
+        "wq": normal(ks[0], (d_model, n_heads, nope + rope), s),
+        "wkv_a": normal(ks[1], (d_model, kv_lora + rope), s),
+        "kv_norm": init_rmsnorm(kv_lora, dtype),
+        "wk_b": normal(ks[2], (n_heads, nope, kv_lora), s_l),       # W_UK
+        "wv_b": normal(ks[3], (n_heads, kv_lora, v_dim), s_l),      # W_UV
+        "wo": normal(ks[4], (n_heads, v_dim, d_model), 1.0 / math.sqrt(n_heads * v_dim)),
+    }
+
+
+def mla_attention(
+    params: dict,
+    x: Array,                       # [B, S, D]
+    positions: Array,               # [B, S] or [S]
+    cache: Optional[dict] = None,   # {"lat": [L,B,1,lora+rope,Smax], "len": [], "layer": []}
+    theta: float = 10_000.0,
+    block_size: int = DEFAULT_BLOCK,
+) -> tuple[Array, Optional[dict]]:
+    """Multi-head latent attention (DeepSeek-V2/V3).
+
+    Each position keeps one row of `lora + rope` values: the normed latent
+    c and the rotary key k_pe, which all heads share.  Per head, the key is
+    [c @ W_UK, k_pe] and the value c @ W_UV; scores are scaled by
+    1/sqrt(nope + rope).
+
+    Without a cache (training, the full forward) the keys and values are
+    expanded per head.  With a cache the attention is *absorbed*: the new
+    rows are written into the latent stack (`cache_write`, as K/V rows are),
+    the query becomes [q_nope @ W_UK^T, q_pe] and scores the latent rows
+    directly, and the output leaves the latent through W_UV.  No per-head
+    key or value is built from the cache, in decode or in prefill.
+    """
+    B, S, _ = x.shape
+    H, nope, lora = params["wk_b"].shape
+    rope = params["wq"].shape[-1] - nope
+    scale = 1.0 / math.sqrt(nope + rope)
+    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
+    q_nope = q[..., :nope]
+    q_pe = apply_rope(q[..., nope:], positions, "full", theta)
+    kv = x @ params["wkv_a"]                                        # [B, S, lora + rope]
+    c = rmsnorm(kv[..., :lora], params["kv_norm"]["scale"], MLA_NORM_EPS)
+    k_pe = apply_rope(kv[..., None, lora:], positions, "full", theta)  # [B, S, 1, rope]
+
+    if cache is None:
+        k_nope = jnp.einsum("bsl,hnl->bshn", c, params["wk_b"])
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe, (B, S, H, rope))], axis=-1)
+        v = jnp.einsum("bsl,hlv->bshv", c, params["wv_b"])
+        out = blockwise_attention(jnp.concatenate([q_nope, q_pe], axis=-1), k, v,
+                                  causal=True, block_size=block_size, scale=scale)
+        new_cache = None
+    else:
+        start, layer = cache["len"], cache["layer"]
+        rows = jnp.concatenate([c, k_pe[:, :, 0]], axis=-1)[:, :, None]
+        lat = cache_write(cache["lat"], rows, layer, start)
+        new_cache = {"lat": lat}
+        rows = lax.dynamic_index_in_dim(lat, layer, 0, keepdims=False)  # [B, 1, lora+rope, Smax]
+        qa = jnp.concatenate(
+            [jnp.einsum("bshn,hnl->bshl", q_nope, params["wk_b"]), q_pe], axis=-1)
+        # the values are the rows' first `lora` values; they are read as the
+        # whole rows and the output sliced, since a slice of the rows as the
+        # value operand makes XLA copy the layer's rows out of the stack
+        if S == 1:
+            o_lat = cached_decode_attention(qa, rows, rows, start + 1, scale)
+        else:
+            rows = rows.transpose(0, 3, 1, 2)
+            o_lat = blockwise_attention(
+                qa, rows, rows, causal=True, q_offset=start, block_size=block_size,
+                kv_valid_len=start + S, scale=scale,
+            )
+        out = jnp.einsum("bshl,hlv->bshv", o_lat[..., :lora], params["wv_b"])
+
+    y = jnp.einsum("bshv,hvd->bsd", out, params["wo"])
     return shard(y, "act_btd"), new_cache
 
 

@@ -20,7 +20,29 @@ all-reduce traffic).
 
 Capacity drops (`pos_in_expert >= capacity`) are the paper's bounded-buffer
 semantics; dropped tokens fall through on the residual path (standard
-GShard/Switch behavior).
+GShard/Switch behavior).  That is the *training* dispatch (`moe_ffn`).
+
+Serving uses `moe_serve`, which drops no token: a served token's output may
+not depend on what else shares its batch.  So do weights that hold only a
+share of the routed experts, with or without a cache: `moe_ffn` dispatches
+over every expert.  It routes over every expert,
+computes only the experts this program holds (`params["experts"]` holds
+`n_held` of them, global ids `first .. first + n_held - 1`), for the
+(token, expert) pairs routed to them: the pairs are sorted by expert and
+run through `jax.lax.ragged_dot`, which reads each held expert's weights
+once.  The TPU compiler lowers it to a grouped matmul that computes whole
+row tiles per group (256 rows on a v5e at Moonlight's widths), so below a
+tile of pairs an expert the work is fixed per held expert, not per pair:
+at 12 pairs an expert a decode tick it computes 16 x 256 rows where a
+dense product of every token against every held expert would compute
+16 x 128 (PERF.md, Open questions).  What experts held elsewhere would add
+is left out: on one chip the layer runs without its exchange (ROADMAP
+Reach 5).
+
+Routers (`route`): `softmax` (top-k of the softmax, renormalised) and
+`sigmoid`, DeepSeek-V3's `noaux_tc`: f32 logits, sigmoid scores, top-k of
+score + `router_bias` (`e_score_correction_bias`), weights the chosen
+scores without the bias, normalised to sum 1 and scaled.
 """
 
 from __future__ import annotations
@@ -44,25 +66,51 @@ class MoEMetrics(NamedTuple):
 
 
 def init_moe(rng, d_model: int, n_experts: int, d_ff: int, mlp_type: str = "swiglu",
-             shared_ff: int = 0, dtype=jnp.bfloat16) -> dict:
+             shared_ff: int = 0, dtype=jnp.bfloat16, router: str = "softmax",
+             n_held: int = 0) -> dict:
+    """Router over all `n_experts`; weights for the first `n_held` (0: all)."""
     ks = jax.random.split(rng, 5)
     s_in, s_out = 1.0 / math.sqrt(d_model), 1.0 / math.sqrt(d_ff)
+    h = n_held or n_experts
     p = {
         "router": (jax.random.normal(ks[0], (d_model, n_experts)) * s_in).astype(jnp.float32),
         "experts": {
-            "w_in": (jax.random.normal(ks[1], (n_experts, d_model, d_ff)) * s_in).astype(dtype),
-            "w_out": (jax.random.normal(ks[2], (n_experts, d_ff, d_model)) * s_out).astype(dtype),
+            "w_in": (jax.random.normal(ks[1], (h, d_model, d_ff)) * s_in).astype(dtype),
+            "w_out": (jax.random.normal(ks[2], (h, d_ff, d_model)) * s_out).astype(dtype),
         },
     }
     if mlp_type == "swiglu":
         p["experts"]["w_gate"] = (
-            jax.random.normal(ks[3], (n_experts, d_model, d_ff)) * s_in
+            jax.random.normal(ks[3], (h, d_model, d_ff)) * s_in
         ).astype(dtype)
+    if router == "sigmoid":
+        p["router_bias"] = jnp.zeros((n_experts,), jnp.float32)
     if shared_ff:
         from .layers import init_mlp
 
         p["shared"] = init_mlp(ks[4], d_model, shared_ff, mlp_type, dtype)
     return p
+
+
+def route(params: dict, x: Array, top_k: int, router: str = "softmax",
+          scale: float = 1.0) -> tuple[Array, Array, Array]:
+    """Route tokens x [..., D] over all experts: (weights [..., k] f32,
+    expert ids [..., k], per-expert scores [..., E] summing to 1, for the
+    load-balance loss)."""
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32), params["router"])
+    if router == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        w, idx = jax.lax.top_k(probs, top_k)
+        w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+    elif router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores + params["router_bias"], top_k)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+        probs = scores / scores.sum(-1, keepdims=True)
+    else:
+        raise ValueError(f"unknown router {router!r}")
+    return w * scale, idx, probs
 
 
 def _n_groups(B: int) -> int:
@@ -84,7 +132,10 @@ def moe_ffn(
     top_k: int,
     capacity_factor: float = 1.25,
     mlp_type: str = "swiglu",
+    router: str = "softmax",
+    scale: float = 1.0,
 ) -> tuple[Array, MoEMetrics]:
+    """Capacity-bounded dispatch (training), over every expert."""
     B, S, D = x.shape
     E = params["router"].shape[1]
     G = _n_groups(B)
@@ -95,15 +146,13 @@ def moe_ffn(
     xt = shard_spec(xt, P(dp, None, None))
 
     # ---- routing (grouped)
-    logits = jnp.einsum("gtd,de->gte", xt.astype(jnp.float32), params["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, top_k)           # [G, Tg, k]
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    gate_vals, expert_idx, probs = route(params, xt, top_k, router, scale)  # [G, Tg, k]
 
     # ---- aux losses (global)
     me = probs.mean((0, 1))
     ce = jnp.zeros((E,)).at[expert_idx.reshape(-1)].add(1.0) / (G * Tg * top_k)
     aux = E * jnp.sum(me * ce)
+    logits = jnp.einsum("gtd,de->gte", xt.astype(jnp.float32), params["router"])
     zloss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
 
     # ---- sort-based dispatch per group (DSDE packing, §4.2)
@@ -167,3 +216,62 @@ def moe_ffn(
         y = y + mlp(params["shared"], x, mlp_type)
 
     return y, MoEMetrics(aux, zloss, drop)
+
+
+def moe_serve(
+    params: dict,
+    x: Array,                 # [B, S, D]
+    top_k: int,
+    router: str = "softmax",
+    scale: float = 1.0,
+    mlp_type: str = "swiglu",
+    first: int = 0,
+    lanes: Array | None = None,
+) -> tuple[Array, Array]:
+    """Dropless expert layer over the held experts (serving).
+
+    Routes every token over all experts; computes the experts held here,
+    global ids `first .. first + n_held - 1`, for exactly the pairs routed
+    to them, and the shared experts for every token.  Returns (y, counts):
+    counts uint32 [4] = pairs routed to held experts, all routed pairs, the
+    busiest held expert's pairs, and 1 (this layer, for the count of layers
+    counted).  `lanes` (bool [B], default all) says whose pairs the counts
+    count: a serving engine's decode computes every lane, free ones too,
+    and counts only the lanes that carry a request.
+    """
+    B, S, D = x.shape
+    T = B * S
+    ex = params["experts"]
+    n_held = ex["w_in"].shape[0]
+    xt = x.reshape(T, D)
+    w, idx, _ = route(params, xt, top_k, router, scale)           # [T, k]
+
+    local = idx.reshape(-1) - first
+    held = (local >= 0) & (local < n_held)
+    key = jnp.where(held, local, n_held)                          # not held -> last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((n_held + 1,), jnp.int32).at[key].add(1)[:n_held]
+    xs = xt[order // top_k]                                       # [T*k, D], by expert
+    # rows past sum(sizes) are the pairs of experts held elsewhere: no group
+    # covers them, and their outputs are masked out below
+    h = jax.lax.ragged_dot(xs, ex["w_in"], sizes)
+    if mlp_type == "swiglu":
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, ex["w_gate"], sizes)) * h
+    else:
+        h = jax.nn.gelu(h)
+    out = jax.lax.ragged_dot(h, ex["w_out"], sizes)
+    out = out[jnp.argsort(order)].reshape(T, top_k, D)            # back to (token, k)
+    out = jnp.where(held.reshape(T, top_k, 1), out.astype(jnp.float32), 0.0)
+    y = jnp.einsum("tk,tkd->td", w, out).astype(x.dtype).reshape(B, S, D)
+
+    if "shared" in params:
+        from .layers import mlp
+
+        y = y + mlp(params["shared"], x, mlp_type)
+    counted = jnp.ones((T * top_k,), bool) if lanes is None else \
+        jnp.repeat(jnp.broadcast_to(lanes[:, None], (B, S)).reshape(T), top_k)
+    csizes = jnp.zeros((n_held + 1,), jnp.int32).at[
+        jnp.where(counted, key, n_held)].add(1)[:n_held]
+    counts = jnp.stack([csizes.sum(), counted.sum(dtype=jnp.int32), csizes.max(),
+                        jnp.int32(1)]).astype(jnp.uint32)
+    return y, counts

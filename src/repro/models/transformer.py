@@ -14,7 +14,20 @@ path do prefill (S tokens into an empty cache) and decode (S=1).  The K/V
 cache is one stacked buffer per tensor, [L, B, Hkv, hd, Smax], carried
 through the layer scan: each layer writes its new rows into it and reads its
 rows from it (`_attn_block`), so a caller that donates the cache gets it
-updated in place.
+updated in place.  A latent-attention model (`kv_lora_rank`) keeps one
+stack of latent rows instead, [L, B, 1, lora + rope, Smax], under the same
+contract (DESIGN.md §9.5).
+
+The `moe` family may lead with `first_k_dense` dense layers
+(`params["dense_blocks"]`); they run through the same layer body, in a scan
+of their own, before the expert layers' scan, and write the same cache
+stack at layers 0 .. first_k_dense - 1.  With a cache, expert layers serve
+dropless over the experts held here (`moe.moe_serve`), and each decode step
+adds its routed-pair counts to `cache["experts"]` (uint32 [4]: pairs on held
+experts, all routed pairs, the busiest held expert's pairs, expert layers),
+summed over the expert layers and over the lanes that an optional
+`cache["lanes"]` (bool [B]) marks, which the serving engine reads outside its
+decode ticks.
 """
 
 from __future__ import annotations
@@ -58,10 +71,12 @@ class ForwardOut(NamedTuple):
 # ============================================================ init
 def _init_attn_layer(rng, cfg: ArchConfig) -> dict:
     k1, k2 = jax.random.split(rng)
-    return {
-        "ln1": L.init_rmsnorm(cfg.d_model),
-        "attn": L.init_attention(k1, cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd, cfg.qkv_bias),
-    }
+    if cfg.kv_lora_rank:
+        attn = L.init_mla(k1, cfg.d_model, cfg.n_heads, cfg.kv_lora_rank,
+                          cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim)
+    else:
+        attn = L.init_attention(k1, cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd, cfg.qkv_bias)
+    return {"ln1": L.init_rmsnorm(cfg.d_model), "attn": attn}
 
 
 def _init_ffn(rng, cfg: ArchConfig, is_moe: bool) -> dict:
@@ -69,7 +84,8 @@ def _init_ffn(rng, cfg: ArchConfig, is_moe: bool) -> dict:
         return {
             "ln2": L.init_rmsnorm(cfg.d_model),
             "moe": X.init_moe(rng, cfg.d_model, cfg.moe_experts, cfg.moe_d_ff,
-                              cfg.mlp_type, cfg.moe_shared_ff),
+                              cfg.mlp_type, cfg.moe_shared_ff, router=cfg.moe_router,
+                              n_held=cfg.moe_held),
         }
     return {"ln2": L.init_rmsnorm(cfg.d_model), "mlp": L.init_mlp(rng, cfg.d_model, cfg.d_ff, cfg.mlp_type)}
 
@@ -89,10 +105,16 @@ def init_lm(rng, cfg: ArchConfig) -> dict:
             rngs, lambda r: {**_init_attn_layer(r, cfg), **_init_ffn(jax.random.fold_in(r, 1), cfg, False)}
         )
     elif cfg.family == "moe":
-        rngs = jax.random.split(ks[1], cfg.n_layers)
+        k = cfg.first_k_dense
+        rngs = jax.random.split(ks[1], cfg.n_layers - k)
         params["blocks"] = _stack(
             rngs, lambda r: {**_init_attn_layer(r, cfg), **_init_ffn(jax.random.fold_in(r, 1), cfg, True)}
         )
+        if k:
+            params["dense_blocks"] = _stack(
+                jax.random.split(ks[2], k),
+                lambda r: {**_init_attn_layer(r, cfg), **_init_ffn(jax.random.fold_in(r, 1), cfg, False)},
+            )
     elif cfg.family == "hybrid":
         period = cfg.attn_period
         n_p = cfg.n_layers // period
@@ -161,14 +183,21 @@ def init_lm(rng, cfg: ArchConfig) -> dict:
 # ============================================================ caches
 def init_cache(cfg: ArchConfig, batch: int, max_seq: int) -> dict:
     """Decode cache pytree (stacked per scan period).  K/V rows are stored
-    [L, B, Hkv, hd, Smax], the layout decode attention reads in place."""
+    [L, B, Hkv, hd, Smax], latent rows [L, B, 1, lora + rope, Smax]: the
+    layouts decode attention reads in place."""
     def kv(n_layers):
+        if cfg.kv_lora_rank:
+            width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+            return {"lat": jnp.zeros((n_layers, batch, 1, width, max_seq), jnp.bfloat16)}
         return {
             "k": jnp.zeros((n_layers, batch, cfg.n_kv_heads, cfg.hd, max_seq), jnp.bfloat16),
             "v": jnp.zeros((n_layers, batch, cfg.n_kv_heads, cfg.hd, max_seq), jnp.bfloat16),
         }
 
-    if cfg.family in ("dense", "vlm", "moe"):
+    if cfg.family == "moe":
+        return {"kv": kv(cfg.n_layers), "len": jnp.zeros((), jnp.int32),
+                "experts": jnp.zeros((4,), jnp.uint32)}
+    if cfg.family in ("dense", "vlm"):
         return {"kv": kv(cfg.n_layers), "len": jnp.zeros((), jnp.int32)}
     if cfg.family == "hybrid":
         n_p = cfg.n_layers // cfg.attn_period
@@ -206,10 +235,12 @@ def _attn_block(cfg, blk, h, positions, kv, layer, cache_len, cross_kv=None):
     attends over that layer's rows.  Every family's cached scan body goes
     through here.  Returns (h, kv)."""
     cache = None if kv is None else {**kv, "len": cache_len, "layer": layer}
-    y, new_cache = L.attention(
-        blk["attn"], L.rmsnorm(h, blk["ln1"]["scale"], cfg.norm_eps),
-        positions, cfg.rope_style, causal=True, cache=cache,
-    )
+    xn = L.rmsnorm(h, blk["ln1"]["scale"], cfg.norm_eps)
+    if cfg.kv_lora_rank:
+        y, new_cache = L.mla_attention(blk["attn"], xn, positions, cache, cfg.rope_theta)
+    else:
+        y, new_cache = L.attention(blk["attn"], xn, positions, cfg.rope_style,
+                                   causal=True, cache=cache, theta=cfg.rope_theta)
     h = h + y
     if cross_kv is not None:
         yx, _ = L.attention(
@@ -220,13 +251,23 @@ def _attn_block(cfg, blk, h, positions, kv, layer, cache_len, cross_kv=None):
     return h, new_cache
 
 
-def _ffn_block(cfg, blk, h):
-    """Channel mixer; returns (h, aux, z)."""
+def _ffn_block(cfg, blk, h, serve=False, lanes=None):
+    """Channel mixer; returns (h, aux, z, counts).  `serve` (a cache is
+    present) takes expert layers through the dropless `moe_serve`, whose
+    routed-pair counts, of the lanes `lanes` marks, come back as `counts`;
+    otherwise counts is None.  Weights that hold a share of the routed
+    experts are a serving deployment's, and serve without a cache too."""
     xn = L.rmsnorm(h, blk["ln2"]["scale"], cfg.norm_eps)
+    zero = jnp.zeros(())
+    if "moe" in blk and (serve or blk["moe"]["experts"]["w_in"].shape[0] < cfg.moe_experts):
+        y, counts = X.moe_serve(blk["moe"], xn, cfg.moe_top_k, cfg.moe_router,
+                                cfg.moe_route_scale, cfg.mlp_type, lanes=lanes)
+        return h + y, zero, zero, (counts if serve else None)
     if "moe" in blk:
-        y, met = X.moe_ffn(blk["moe"], xn, cfg.moe_top_k, mlp_type=cfg.mlp_type)
-        return h + y, met.aux_loss, met.router_z_loss
-    return h + L.mlp(blk["mlp"], xn, cfg.mlp_type), jnp.zeros(()), jnp.zeros(())
+        y, met = X.moe_ffn(blk["moe"], xn, cfg.moe_top_k, mlp_type=cfg.mlp_type,
+                           router=cfg.moe_router, scale=cfg.moe_route_scale)
+        return h + y, met.aux_loss, met.router_z_loss, None
+    return h + L.mlp(blk["mlp"], xn, cfg.mlp_type), zero, zero, None
 
 
 def forward(
@@ -249,18 +290,31 @@ def forward(
 
     if cfg.family in ("dense", "vlm", "moe"):
         kv = cache["kv"] if cache is not None else None
+        # routed-pair counters: summed over the expert layers of decode
+        # steps, over the lanes `cache["lanes"]` marks (default: all)
+        ex = cache.get("experts") if cache is not None else None
+        lanes = cache.get("lanes") if cache is not None else None
 
         def body(carry, xs):
-            h, aux, zl, kv = carry
+            h, aux, zl, kv, ex = carry
             blk, i = xs
             h, kv = _attn_block(cfg, blk, h, positions, kv, i, start)
-            h, a, z = _ffn_block(cfg, blk, h)
-            return (h, aux + a, zl + z, kv), None
+            h, a, z, counts = _ffn_block(cfg, blk, h, serve=cache is not None, lanes=lanes)
+            if counts is not None and ex is not None and S == 1:
+                ex = ex + counts
+            return (h, aux + a, zl + z, kv, ex), None
 
-        (h, aux, zl, kv), _ = lax.scan(
-            _maybe_remat(body), (h, aux, zl, kv), (params["blocks"], jnp.arange(cfg.n_layers))
+        carry = (h, aux, zl, kv, ex)
+        k = cfg.first_k_dense if "dense_blocks" in params else 0
+        if k:
+            carry, _ = lax.scan(_maybe_remat(body), carry,
+                                (params["dense_blocks"], jnp.arange(k)))
+        (h, aux, zl, kv, ex), _ = lax.scan(
+            _maybe_remat(body), carry, (params["blocks"], jnp.arange(k, cfg.n_layers))
         )
         new_cache = None if cache is None else {"kv": kv, "len": start + S}
+        if ex is not None:
+            new_cache["experts"] = ex
 
     elif cfg.family == "hybrid":
         period = cfg.attn_period
@@ -293,7 +347,7 @@ def forward(
                 is_moe = j % cfg.moe_every == cfg.moe_every - 1
                 key = "moe" if is_moe else "mlp"
                 fp = jax.tree.map(lambda x, i=ffn_i[key]: x[i], per[key])
-                h, a, z = _ffn_block(cfg, fp, h)
+                h, a, z, _ = _ffn_block(cfg, fp, h, serve=True)
                 ffn_i[key] += 1
                 aux, zl = aux + a, zl + z
             return (h, aux, zl, kv), mst_out
@@ -316,7 +370,7 @@ def forward(
                     is_moe = j % cfg.moe_every == cfg.moe_every - 1
                     key = "moe" if is_moe else "mlp"
                     fp = jax.tree.map(lambda x, i=ffn_i[key]: x[i], per[key])
-                    h, a, z = _ffn_block(cfg, fp, h)
+                    h, a, z, _ = _ffn_block(cfg, fp, h)
                     ffn_i[key] += 1
                     aux, zl = aux + a, zl + z
                 return (h, aux, zl), None
@@ -394,7 +448,7 @@ def forward(
             xk = jnp.einsum("bsd,dhk->bshk", enc_out, blk["xattn"]["wk"])
             xv = jnp.einsum("bsd,dhk->bshk", enc_out, blk["xattn"]["wv"])
             h, kv = _attn_block(cfg, blk, h, positions, kv, i, start, cross_kv=(xk, xv))
-            h, a, z = _ffn_block(cfg, blk, h)
+            h, a, z, _ = _ffn_block(cfg, blk, h)
             return (h, aux + a, zl + z, kv), None
 
         (h, aux, zl, kv), _ = lax.scan(
@@ -420,7 +474,7 @@ def encode(params: dict, cfg: ArchConfig, frames: Array) -> Array:
             positions, "none", causal=False,
         )
         h = h + y
-        h, _, _ = _ffn_block(cfg, blk, h)
+        h, _, _, _ = _ffn_block(cfg, blk, h)
         return h, None
 
     h, _ = lax.scan(_maybe_remat(body), h, params["enc_blocks"])

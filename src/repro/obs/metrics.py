@@ -165,6 +165,7 @@ class MetricsRegistry:
 
     def __init__(self):
         self._metrics: dict[tuple, object] = {}
+        self._collectors: list = []
 
     def _get(self, kind: str, cls, name: str, labels: dict):
         key = (kind, name, _label_key(labels))
@@ -201,9 +202,16 @@ class MetricsRegistry:
                 self.gauge(name, **labels).set(v)
             # lists / strings: trace-side detail, not a metric
 
+    def collector(self, fn) -> None:
+        """Call `fn()` before each `flat()`, until it returns False: for
+        values a component keeps elsewhere, such as on a device, and reads
+        back only when it is scraped."""
+        self._collectors.append(fn)
+
     # ---------------------------------------------------------------- export
     def flat(self) -> dict:
         """Deterministic flat dict: ``name{labels}`` -> value/summary."""
+        self._collectors = [fn for fn in self._collectors if fn() is not False]
         out = {}
         for (kind, name, labels) in sorted(self._metrics, key=lambda k: (k[1], k[2], k[0])):
             m = self._metrics[(kind, name, labels)]

@@ -59,6 +59,20 @@ per program, `decode` or `prefill`:
     that added an entry to that jitted program's cache (each new prompt
     length compiles a prefill), and their seconds: trace, lower, and compile
     or load from the persistent cache.
+
+Expert counters, for a model whose cache carries ``experts`` (the `moe`
+family): the decode program adds, in each expert layer of each tick, the
+(token, expert) pairs routed to the experts held here, all routed pairs,
+and the busiest held expert's pairs, over the lanes that carry a request
+(``cache["lanes"]``, made each tick under the span ``serve.experts.lanes``;
+free lanes are computed but not counted), to that donated device state
+(`models.transformer`).  Nothing reads it inside a
+decode tick: a scrape of the registry (`MetricsRegistry.flat`) reads it
+back once, under the span ``serve.experts.collect``, and adds what is new
+to ``serve.experts.held_pairs`` / ``.routed_pairs`` / ``.peak_pairs`` /
+``.layer_steps`` (expert layers x decode ticks); the gauge
+``serve.experts.held`` gives the number of experts the served weights hold,
+set with the lanes.
 """
 
 from __future__ import annotations
@@ -67,6 +81,7 @@ import dataclasses
 import queue
 import threading
 import time
+import weakref
 from typing import NamedTuple, Optional
 
 import jax
@@ -79,6 +94,10 @@ from repro.obs import flight as obs_flight
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.obs.trace import profiled_span
+
+
+# the device's expert counters, in the order `models.moe.moe_serve` counts them
+EXPERT_COUNTERS = ("held_pairs", "routed_pairs", "peak_pairs", "layer_steps")
 
 
 class LockDisciplineError(RuntimeError):
@@ -166,6 +185,24 @@ class ServeEngine:
         # perf_counter() when the last device result reached the host, or
         # None when no host gap is open (module docstring)
         self._t_result: Optional[float] = None
+        if "experts" in self.cache:
+            self._expert_counters = [registry.counter(f"serve.experts.{n}")
+                                     for n in EXPERT_COUNTERS]
+            self._experts_held = registry.gauge("serve.experts.held")
+            self._experts_seen = np.zeros(len(EXPERT_COUNTERS), np.uint32)
+            me = weakref.ref(self)
+            registry.collector(lambda: (e := me()) is not None and e._collect_experts())
+
+    def _collect_experts(self) -> bool:
+        """Add the device's expert counts since the last scrape to the
+        registry: one read-back, outside every decode tick.  The device
+        counters are uint32 and wrap; the difference wraps with them."""
+        with profiled_span("serve.experts.collect"), self._cache_mu:
+            now = np.asarray(self.cache["experts"])
+        for counter, d in zip(self._expert_counters, (now - self._experts_seen).tolist()):
+            counter.inc(int(d))
+        self._experts_seen = now
+        return True
 
     # --------------------------------------------------------- plumbing
     def _prefill_impl(self, params, cache, tokens, slot, plen):
@@ -331,11 +368,19 @@ class ServeEngine:
                     # (static shapes); per-slot masking comes from
                     # kv_valid_len in attention
                     cache_len = jnp.asarray(int(self.slot_pos.max()), jnp.int32)
+                    lanes = None
+                    if "experts" in self.cache:
+                        with profiled_span("serve.experts.lanes"):
+                            lanes = jnp.asarray(np.isin(np.arange(self.n_slots), active))
+                            ex = self.params["blocks"]["moe"]["experts"]
+                            self._experts_held.set(ex["w_in"].shape[1])
                 # the cache dict is copied under the mutex: an admitting
                 # thread may swap `self.cache` until we hold it
                 with profiled_span("serve.decode.launch"), self._cache_mu:
                     cache = dict(self.cache)
                     cache["len"] = cache_len
+                    if lanes is not None:
+                        cache["lanes"] = lanes
                     logits, self.cache = self._launch(
                         self._decode, self._decode_counters, self.params, tokens, cache)
                 with profiled_span("serve.decode.readback"):

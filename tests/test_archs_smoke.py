@@ -76,7 +76,7 @@ def test_prefill_decode_smoke(arch):
 
 
 @pytest.mark.parametrize("arch", ["qwen1.5-110b", "jamba-v0.1-52b", "xlstm-1.3b", "whisper-small",
-                                  "smollm-360m", "chatglm3-6b"])
+                                  "smollm-360m", "chatglm3-6b", "moonlight-16b-a3b"])
 def test_decode_matches_full_forward(arch):
     """Prefill(S) + decode(1) logits == forward over S+1 tokens at position S.
 
@@ -86,7 +86,10 @@ def test_decode_matches_full_forward(arch):
     carried cache and reads the layer's rows in place (`cache_write`,
     `cached_decode_attention`); smollm-360m (three query heads to a kv head
     in its smoke form) and chatglm3-6b (qkv bias, rotary on half the head)
-    are the two configurations the chip benchmark serves.
+    are the two configurations the chip benchmark serves.  moonlight-16b-a3b
+    decodes in the absorbed form over its latent cache, against the full
+    forward's expanded attention, with a leading dense layer and dropless
+    experts over a held share of 4 of 8.
     """
     cfg = get_config(arch, smoke=True)
     model = build_model(cfg)

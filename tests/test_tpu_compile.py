@@ -13,6 +13,7 @@ tests in this one file, so that one worker loads the library.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 
@@ -76,6 +77,30 @@ def test_smollm_decode_step_fits_one_chip(one_chip):
     used_donated, alias = compiled_memory(donate_argnames="cache")
     assert alias >= cache_bytes, (alias, cache_bytes)
     assert used_donated < used, (used_donated, used)
+
+
+def test_moonlight_decode_step_fits_one_chip(one_chip):
+    """Full-width Moonlight-16B-A3B decode step at the benchmark's serving
+    shape (16 of 64 experts held, 128 slots of 1024 latent positions),
+    compiled with the cache donated as `ServeEngine` compiles it: weights and
+    latent cache fit one chip, the cache is updated in place, and the held
+    experts run in the compiler's grouped matmul (the ragged-dot kernel),
+    not in a matmul of every token against every expert."""
+    model = build_model(dataclasses.replace(get_config("moonlight-16b-a3b"), moe_held=16))
+    params = _on(one_chip, model.init_shapes())
+    cache = _on(one_chip, jax.eval_shape(lambda: model.init_cache(128, 1024)))
+    token = jax.ShapeDtypeStruct((128,), jnp.int32, sharding=one_chip)
+    lat_bytes = cache["kv"]["lat"].size * 2
+    assert lat_bytes == 128 * 1024 * 27 * 576 * 2          # 31,104 B a position
+    compiled = jax.jit(model.decode_step, donate_argnames="cache").lower(
+        params, token, cache).compile()
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert ma.alias_size_in_bytes >= lat_bytes
+    assert used < HBM_BYTES, used
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("pt,hd,n_pages,m,k", [
